@@ -53,7 +53,7 @@ PEAK_FLOPS = 197e12  # per-chip bf16 rate (mirrors hlo_analysis.PEAK_FLOPS)
 def bench_hierarchy(quick: bool = False) -> list[dict]:
     """Run the flat-vs-hierarchical comparison in a forced-device subprocess."""
     n_dev = 32 if quick else 512
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={n_dev}",
                PYTHONPATH=os.pathsep.join(
                    [os.path.abspath("src"), os.path.abspath("."),
@@ -76,7 +76,6 @@ def _sim_time_s(by_level_total: list[float], chips: int) -> float:
 def _sub_main(quick: bool) -> None:
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from benchmarks.records import emit_record
@@ -107,8 +106,8 @@ def _sub_main(quick: bool) -> None:
                                   lane_parallel=True)
 
     def _walk(fn, in_specs=P("dp"), args=(sds,)):
-        f = jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=P("dp"), check_rep=False))
+        f = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                  out_specs=P("dp"), check_vma=False))
         hlo = f.lower(*args).compile().as_text()
         return hlo_cost.analyze_hlo(hlo, intra_group_size=group,
                                     level_sizes=level_sizes,
@@ -227,9 +226,9 @@ def _sub_main(quick: bool) -> None:
         settled = ccache.settle_inflight(u, "dp", mf.ADD, plan3_defer)
         return settled, y[None]
 
-    f = jax.jit(shard_map(overlap_land, mesh=mesh,
-                          in_specs=(P("dp"), P("dp")),
-                          out_specs=(P("dp"), P("dp")), check_rep=False))
+    f = jax.jit(jax.shard_map(overlap_land, mesh=mesh,
+                              in_specs=(P("dp"), P("dp")),
+                              out_specs=(P("dp"), P("dp")), check_vma=False))
     ovl_hlo = f.lower(sds, wsds).compile().as_text()
     ovl_walk = hlo_cost.analyze_hlo(ovl_hlo, intra_group_size=group,
                                     level_sizes=level_sizes,

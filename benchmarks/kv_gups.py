@@ -48,7 +48,7 @@ N_SHARDS = 8
 
 
 def bench_kv_gups(quick: bool = False) -> list[dict]:
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={N_SHARDS}",
                PYTHONPATH=os.pathsep.join(
                    [os.path.abspath("src"), os.path.abspath("."),
@@ -67,8 +67,6 @@ def _sub_main(quick: bool) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
 
     from benchmarks.records import emit_record
     from benchmarks.traces import key_stream
@@ -88,14 +86,10 @@ def _sub_main(quick: bool) -> None:
     n_users = 1 << 20
     axis = "shards"
 
-    mesh = build_mesh(S, axis)
-    spmd = mesh_spmd(mesh, axis)
-    # interpret-mode Pallas on CPU measures the interpreter, not the
-    # kernel — scatter through the jnp oracle off-TPU (both stores use
-    # the same scatter either way; the contest is the merge bill).
-    use_pallas = jax.default_backend() == "tpu"
-    cfg = KVConfig(n_keys=R, cols=D, dtype=jnp.int32,
-                   use_pallas=use_pallas)
+    spmd = mesh_spmd(build_mesh(S, axis), axis)
+    # both stores use the same scatter (the backend picks it); the
+    # contest is the merge bill.
+    cfg = KVConfig(n_keys=R, cols=D, dtype=jnp.int32)
     plan_sync = serving_plan(S, "none")
     plan_priv = serving_plan(S, "all")
     sync = ShardedKV(cfg, S, spmd, plan=plan_sync)
@@ -167,14 +161,7 @@ def _sub_main(quick: bool) -> None:
         group *= sz
 
     def _walk(fn, *args):
-        def region(*locals_):
-            loc = [jax.tree.map(lambda x: x[0], a) for a in locals_]
-            out = fn(*loc)
-            return jax.tree.map(lambda x: x[None], out)
-        f = jax.jit(shard_map(region, mesh=mesh,
-                              in_specs=(P(axis),) * len(args),
-                              out_specs=P(axis), check_rep=False))
-        hlo = f.lower(*args).compile().as_text()
+        hlo = spmd.lower(fn, *args).compile().as_text()
         return hlo_cost.analyze_hlo(hlo, intra_group_size=group,
                                     level_sizes=sizes, level_names=names)
 
@@ -238,8 +225,7 @@ def _sub_main(quick: bool) -> None:
     # tick gets cheaper (an O(B) append instead of a table-wide scatter).
     from repro.core.defer_schedule import (AdaptiveDeferSchedule,
                                            DeferSchedule)
-    pcfg = KVConfig(n_keys=R, cols=D, dtype=jnp.int32,
-                    use_pallas=use_pallas, partitioned=True)
+    pcfg = KVConfig(n_keys=R, cols=D, dtype=jnp.int32, partitioned=True)
     part = ShardedKV(pcfg, S, spmd, plan=plan_priv, commit_every=K)
     part_ov = ShardedKV(pcfg, S, spmd, plan=plan_priv,
                         schedule=DeferSchedule.fixed(

@@ -25,7 +25,7 @@ from benchmarks.records import emit_record, iter_records
 
 def _sub(mode: str) -> list[dict]:
     """Run a sub-benchmark in a subprocess with 8 forced host devices."""
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=os.pathsep.join(
                    [os.path.abspath("src"), os.path.abspath("."),
@@ -88,7 +88,6 @@ def _merges_main() -> None:
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from repro.core import ccache, merge_functions as mf
     from repro.launch import hlo_cost
@@ -107,8 +106,8 @@ def _merges_main() -> None:
             u, "data", mf.saturating_add(1e9), force_tree=True),
     }
     for name, fn in cases.items():
-        f = jax.jit(shard_map(fn, mesh=mesh, in_specs=P("data"),
-                              out_specs=P("data")))
+        f = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=P("data"),
+                                  out_specs=P("data")))
         lowered = f.lower(jax.ShapeDtypeStruct((8, n), jnp.float32))
         compiled = lowered.compile()
         walk = hlo_cost.analyze_hlo(compiled.as_text())

@@ -164,7 +164,7 @@ def bench_apps_sharded(quick: bool = False) -> list[dict]:
     import subprocess
     import sys
     n_dev = 8 if quick else 16
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={n_dev}",
                PYTHONPATH=os.pathsep.join(
                    [os.path.abspath("src"), os.path.abspath("."),
@@ -181,14 +181,12 @@ def bench_apps_sharded(quick: bool = False) -> list[dict]:
 
 def _apps_sub_main(quick: bool) -> None:
     import jax
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
     import jax.numpy as jnp
 
     from benchmarks.records import emit_record
     from repro.apps import bfs_superstep, pagerank_superstep
     from repro.apps.common import default_plan
-    from repro.apps.sharded import build_mesh, run_app
+    from repro.apps.sharded import build_mesh, mesh_spmd, run_app
     from repro.core import ccache
     from repro.core.merge_functions import ADD, MIN
     from repro.launch import hlo_cost
@@ -209,7 +207,7 @@ def _apps_sub_main(quick: bool) -> None:
 
         # --- per-level wire vectors of the compiled superstep programs ---
         axis = "shards"
-        mesh = build_mesh(n_shards, axis)
+        spmd = mesh_spmd(build_mesh(n_shards, axis), axis)
         plan = default_plan(n_shards)
         plan_d = default_plan(n_shards, defer_top=True)
         sizes = tuple(lv.size for lv in plan.levels)
@@ -224,14 +222,7 @@ def _apps_sub_main(quick: bool) -> None:
         edge_s = jax.ShapeDtypeStruct((n_shards, e_per), jnp.int32)
 
         def _walk(fn, *args):
-            def region(*locals_):
-                loc = [jax.tree.map(lambda x: x[0], a) for a in locals_]
-                out = fn(*loc)
-                return jax.tree.map(lambda x: x[None], out)
-            f = jax.jit(shard_map(region, mesh=mesh,
-                                  in_specs=(P(axis),) * len(args),
-                                  out_specs=P(axis), check_rep=False))
-            hlo = f.lower(*args).compile().as_text()
+            hlo = spmd.lower(fn, *args).compile().as_text()
             return hlo_cost.analyze_hlo(hlo, intra_group_size=group,
                                         level_sizes=sizes, level_names=names)
 

@@ -61,6 +61,7 @@ import numpy as np
 from repro import checkpoint as ckpt
 from repro.configs.base import ShapeConfig, get_smoke_config
 from repro.data.pipeline import batch_at, data_config_for
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_train_step
 from repro.models.module import split_params
 from repro.models.registry import build_model
@@ -190,7 +191,7 @@ def chaos_real_model(quick: bool) -> None:
 
     cfg = get_smoke_config("xlstm_125m")
     shape = ShapeConfig("t", 32, 8, "train")
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_host_mesh(8, 1)
     rules = lowering_rules(cfg, shape, mesh)
     model = build_model(cfg)
     opt = adamw(constant(1e-3))

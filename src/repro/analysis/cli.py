@@ -149,7 +149,7 @@ def sweep_apps(report: Report, axis_name: str = "shards",
 
 
 def sweep_serve(report: Report, timeout: int = 1800) -> None:
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=("--xla_force_host_platform_device_count="
                           f"{_SERVE_SHARDS}"),
                PYTHONPATH=os.pathsep.join(
@@ -178,8 +178,6 @@ def _sub_serve() -> None:
     """Child half of :func:`sweep_serve`; emits tagged JSON on stdout."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
 
     from repro.analysis import placement
     from repro.analysis.jaxpr import (audit_plan, check_kv_tick_taint,
@@ -197,8 +195,7 @@ def _sub_serve() -> None:
 
     S = _SERVE_SHARDS
     axis = "shards"
-    mesh = build_mesh(S, axis)
-    spmd = mesh_spmd(mesh, axis)
+    spmd = mesh_spmd(build_mesh(S, axis), axis)
     on_cpu = jax.default_backend() == "cpu"
     R, D, B = 256, 2, 32
     cfg = KVConfig(n_keys=R, cols=D, dtype=jnp.int32)
@@ -213,16 +210,8 @@ def _sub_serve() -> None:
             lambda s: jax.ShapeDtypeStruct((S,) + s.shape, s.dtype), specs)
 
     def walk(fn, specs, donate=()):
-        def region(*locals_):
-            loc = [jax.tree.map(lambda x: x[0], a) for a in locals_]
-            out = fn(*loc)
-            return jax.tree.map(lambda x: x[None], out)
-
-        f = jax.jit(shard_map(region, mesh=mesh,
-                              in_specs=(P(axis),) * len(specs),
-                              out_specs=P(axis), check_rep=False),
-                    donate_argnums=donate)
-        hlo = f.lower(*batched(specs)).compile().as_text()
+        hlo = spmd.lower(fn, *batched(specs),
+                         donate=donate).compile().as_text()
         return hlo, hlo_cost.analyze_hlo(hlo, level_sizes=sizes,
                                          level_names=names)
 

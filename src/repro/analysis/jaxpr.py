@@ -26,7 +26,7 @@ from __future__ import annotations
 from typing import Any, Optional, Sequence
 
 import jax
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from repro.analysis.diagnostics import Diagnostic
 from repro.core.merge_functions import MergeFn

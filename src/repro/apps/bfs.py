@@ -72,9 +72,11 @@ def run_bfs(dist0, src_sh, dst_sh, spmd, plan, axis_name, *,
     ``dist0``/``src_sh``/``dst_sh`` are shard-major ([S, n], [S, E]);
     ``spmd(fn, *args)`` maps a per-shard function across the shard axis
     with ``axis_name`` bound (vmap in tests, shard_map on meshes).
-    ``defer_k`` routes the plan's deferred levels through ``defer_cascade``
-    committing every ``defer_k`` supersteps; the trailing partial cycle is
-    flushed after the loop. Returns the final shard-major distances.
+    Without ``defer_k`` the loop stops early at the fixpoint (a superstep
+    that changes no distance). ``defer_k`` routes the plan's deferred levels
+    through ``defer_cascade`` committing every ``defer_k`` supersteps; the
+    trailing partial cycle is flushed after the loop. Returns the final
+    shard-major distances.
     """
     n_shards = dist0.shape[0]
     size = n_shards
@@ -91,7 +93,10 @@ def run_bfs(dist0, src_sh, dst_sh, spmd, plan, axis_name, *,
 
         dist = dist0
         for _ in range(supersteps):
-            dist = spmd(step, dist, src_sh, dst_sh)
+            new = spmd(step, dist, src_sh, dst_sh)
+            if bool(jnp.array_equal(new, dist)):
+                return new  # fixpoint: the frontier is empty
+            dist = new
         return dist
 
     # Idempotent merge-on-evict: each superstep's eager-scope join is

@@ -9,9 +9,8 @@ engine over a :class:`~repro.core.merge_plan.MergePlan`).
 The app step functions are axis-generic: they only use collectives through
 ``repro.core.ccache``, so the same code runs under ``jax.vmap(...,
 axis_name=...)`` (fast in-process tests) and ``shard_map`` over a real
-device mesh (the ≥8-device acceptance runs and benchmarks). The scatter
-phase picks the Pallas kernel on real meshes and the pure-jnp oracle under
-vmap (Pallas cannot be batched by vmap on this toolchain).
+device mesh (the acceptance runs and benchmarks). The scatter phase picks
+the Pallas kernel on real meshes and the pure-jnp oracle under vmap.
 """
 
 from __future__ import annotations
@@ -19,29 +18,17 @@ from __future__ import annotations
 from repro.core.merge_plan import MergePlan
 
 
-def scatter(table, ids, vals, *, kind: str, use_pallas: bool = False,
-            block_rows: int | None = None, chunk: int | None = None,
-            interpret: bool | None = None):
+def scatter(table, ids, vals, *, kind: str, use_pallas: bool = False):
     """One shard's scatter phase: fold ``vals`` into ``table`` rows by id.
 
-    ``use_pallas`` selects the real ``cscatter`` kernel (shard_map paths);
+    ``use_pallas`` selects the real ``cscatter`` kernel (compiled on TPU,
+    interpreted elsewhere; its tile comes from ``cscatter.choose_tile``);
     the default is the vmappable jnp oracle. Out-of-range/negative ids are
-    ignored (the padding convention) in both. ``interpret`` threads through
-    to the kernel; ``None`` resolves from the backend (compile on TPU,
-    interpret elsewhere).
+    ignored (the padding convention) in both.
     """
     if use_pallas:
         from repro.kernels.cscatter import cscatter
-        r = table.shape[0]
-        n = ids.shape[0]
-        br = block_rows if block_rows is not None else r
-        ch = chunk if chunk is not None else n
-        if r % br != 0:
-            br = r
-        if n % ch != 0:
-            ch = n
-        return cscatter(table, ids, vals, kind=kind, block_rows=br, chunk=ch,
-                        interpret=interpret)
+        return cscatter(table, ids, vals, kind=kind)
     from repro.kernels.ref import ref_cscatter
     return ref_cscatter(table, ids, vals, kind)
 

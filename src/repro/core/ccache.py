@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core import compat, permutes
+from repro.core import permutes
 from repro.core.merge_functions import MergeFn
 from repro.core.merge_plan import (LevelStage, MergePlan, compile_plan,
                                    split_eager_deferred)
@@ -91,7 +91,7 @@ def tree_merge(update: PyTree, axis_name, merge: MergeFn,
             f"encode/decode wire format — the exchange would silently stay "
             f"uncompressed; use a codec merge (e.g. int8_compressed_add) or "
             f"drop compress")
-    size = compat.axis_size(axis_name)
+    size = lax.axis_size(axis_name)
     if not permutes.is_pow2(size):  # non-power-of-two fallback
         gathered = lax.all_gather(update, axis_name, axis=0, tiled=False)
         def _fold(x):
@@ -195,7 +195,7 @@ def _resolve_plan(topology: Topology, axis_name,
     two-level engine's inter-group semantics.
     """
     axis = topology.resolve_axis(axis_name)
-    size = compat.axis_size(axis)
+    size = lax.axis_size(axis)
     if not isinstance(topology, MergePlan):
         if topology.group_size <= 1 or size == 1:
             return None, axis, size

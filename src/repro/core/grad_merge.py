@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core import ccache, compat
+from repro.core import ccache
 from repro.core.merge_functions import ADD, MergeFn
 
 PyTree = Any
@@ -95,6 +95,6 @@ def merge_gradients(
     # Mean semantics exist exactly for scalable merges (the delayed-mean
     # algebra trait); idempotent/multiplicative merges pass through.
     if mean and merge_fn.scalable:
-        n = compat.axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         merged = jax.tree.map(lambda g: g / n, merged)
     return merged

@@ -1,5 +1,8 @@
 import os
+# A CPU rehearsal of the 512-chip mesh by design: it, and every per-cell
+# child it spawns, stays off any accelerator the host has.
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -85,11 +88,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
         mem["fits_16gb_hbm"] = bool(live < 16 * 1024**3)
         rec["memory"] = mem
 
-        # jax 0.4.37 returns a list of per-program dicts; newer jax returns
-        # the dict directly. Normalize to a single dict either way.
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
         print("cost_analysis flops:", ca.get("flops"),
               "bytes:", ca.get("bytes accessed"))
         rec["cost_analysis_raw"] = {

@@ -39,8 +39,8 @@ def _parse_args(argv=None):
                    help="table rows (counter keys)")
     p.add_argument("--cols", type=int, default=4,
                    help="columns per key")
-    p.add_argument("--shards", type=int, default=8,
-                   help="mesh width (devices)")
+    p.add_argument("--shards", type=int, default=None,
+                   help="mesh width (default: every device)")
     p.add_argument("--ticks", type=int, default=64,
                    help="update batches to ingest")
     p.add_argument("--batch", type=int, default=512,
@@ -102,9 +102,8 @@ import numpy as np  # noqa: E402
 
 def main(argv=None) -> None:
     args = _parse_args(argv)
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from repro.apps.sharded import build_mesh, mesh_spmd
     from repro.core.defer_schedule import (AdaptiveDeferSchedule,
                                            DeferSchedule,
@@ -112,16 +111,14 @@ def main(argv=None) -> None:
     from repro.launch import hlo_cost
     from repro.serve import KVConfig, ShardedKV, serving_plan
 
-    S, R, D, B = args.shards, args.keys, args.cols, args.batch
+    S = args.shards or jax.device_count()
+    R, D, B = args.keys, args.cols, args.batch
     axis = "shards"
-    mesh = build_mesh(S, axis)
-    spmd = mesh_spmd(mesh, axis)
-    use_pallas = jax.default_backend() == "tpu"
+    spmd = mesh_spmd(build_mesh(S, axis), axis)
 
     cfg = KVConfig(n_keys=R, cols=D, dtype=jnp.int32,
                    consistency=args.consistency, engine=args.engine,
-                   ways=args.ways, use_pallas=use_pallas,
-                   partitioned=args.partitioned,
+                   ways=args.ways, partitioned=args.partitioned,
                    spill_blocks=args.spill_blocks)
     sync_mode = args.defer == "sync"
     if args.partitioned and sync_mode:
@@ -143,8 +140,7 @@ def main(argv=None) -> None:
         # by max_period, which the never-committing timer would blow up.
         probe_cfg = KVConfig(n_keys=R, cols=D, dtype=jnp.int32,
                              consistency=args.consistency,
-                             engine=args.engine, ways=args.ways,
-                             use_pallas=use_pallas)
+                             engine=args.engine, ways=args.ways)
         probe = ShardedKV(probe_cfg, S, spmd, plan=serving_plan(S, "none"))
         sizes = tuple(lv.size for lv in plan.levels)
         names = tuple(lv.name for lv in plan.levels)
@@ -152,19 +148,11 @@ def main(argv=None) -> None:
         for sz in sizes[:-1]:
             group *= sz
 
-        def region(tbl, keys, vals):
-            loc = [jax.tree.map(lambda x: x[0], a)
-                   for a in (tbl, keys, vals)]
-            out = probe.raw_tick_fn()(*loc)
-            return jax.tree.map(lambda x: x[None], out)
-
-        f = jax.jit(shard_map(region, mesh=mesh,
-                              in_specs=(P(axis),) * 3,
-                              out_specs=P(axis), check_rep=False))
-        hlo = f.lower(jax.ShapeDtypeStruct((S, R, D), jnp.int32),
-                      jax.ShapeDtypeStruct((S, B), jnp.int32),
-                      jax.ShapeDtypeStruct((S, B, D), jnp.int32)
-                      ).compile().as_text()
+        hlo = spmd.lower(probe.raw_tick_fn(),
+                         jax.ShapeDtypeStruct((S, R, D), jnp.int32),
+                         jax.ShapeDtypeStruct((S, B), jnp.int32),
+                         jax.ShapeDtypeStruct((S, B, D), jnp.int32)
+                         ).compile().as_text()
         walk = hlo_cost.analyze_hlo(hlo, intra_group_size=group,
                                     level_sizes=sizes, level_names=names)
         k0 = np.zeros((S, B), np.int32)

@@ -8,6 +8,7 @@ sets XLA_FLAGS before any jax import and only then builds meshes.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,9 +20,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = 1
     for s in shape:
         n *= s
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=jax.devices()[:n])
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over the host's real/forced devices (tests, examples)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """Small mesh over the host's devices (the train CLI, tests)."""
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ShapeConfig, get_config, get_smoke_config
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import lowering_rules
 from repro.models.module import split_params
@@ -26,7 +27,7 @@ from repro.models.registry import build_model
 from repro.sharding.partition import sharding_rules
 
 
-def main() -> None:
+def main(argv=None) -> None:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)
     p.add_argument("--smoke", action="store_true")
@@ -34,7 +35,8 @@ def main() -> None:
     p.add_argument("--prompt-len", type=int, default=32)
     p.add_argument("--gen", type=int, default=16)
     p.add_argument("--seed", type=int, default=0)
-    args = p.parse_args()
+    args = p.parse_args(argv)
+    compile_cache.enable()
 
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_config(args.arch))

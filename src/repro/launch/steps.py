@@ -92,6 +92,33 @@ def opt_state_axes(opt_specs: OptState, param_axes: PyTree) -> OptState:
     return OptState(step=(), mu=mu_axes, nu=nu)
 
 
+def train_shardings(model, shape_cfg, mesh: Mesh, rules: dict,
+                    param_axes: PyTree, param_specs: PyTree,
+                    opt_specs: OptState) -> tuple[dict, PyTree]:
+    """NamedShardings of the train state ``{"params", "opt"}`` and of a
+    batch, by the logical rules (``specs`` may be arrays or SDS)."""
+    opt_ax = opt_state_axes(opt_specs, param_axes)
+    opt_sh = OptState(
+        step=NamedSharding(mesh, P()),
+        mu=(None if opt_specs.mu is None
+            else axes_to_shardings(opt_ax.mu, opt_specs.mu, mesh, rules)),
+        nu=axes_to_shardings(opt_ax.nu, opt_specs.nu, mesh, rules))
+    state_sh = {"params": axes_to_shardings(param_axes, param_specs, mesh,
+                                            rules),
+                "opt": opt_sh}
+    batch_sh = axes_to_shardings(model.input_axes(shape_cfg),
+                                 model.input_specs(shape_cfg), mesh, rules)
+    return state_sh, batch_sh
+
+
+def defer_shardings(defer_specs: dict, mesh: Mesh, axis) -> dict:
+    """NamedShardings of ``state["defer"]``: the step counter replicated,
+    the pendings (and in-flight buffer) leading-dim sharded over ``axis``."""
+    return {k: (NamedSharding(mesh, P()) if k == "t" else jax.tree.map(
+                lambda _: NamedSharding(mesh, P(axis)), v))
+            for k, v in defer_specs.items()}
+
+
 # ---------------------------------------------------------------------------
 # Train step
 # ---------------------------------------------------------------------------
@@ -145,11 +172,11 @@ def make_train_step(model, cfg, optimizer, num_microbatches: int = 1,
     top-level exchange alongside its own compute, stepping the optimizer
     one step stale (K-step accumulation with a one-step delay).
 
-    All remaining mesh axes (tensor/model parallelism)
-    stay on the compiler via shard_map's ``auto`` set, which is what lets
-    the same step serve the implicit ``plan_train`` path — params keep
-    their model-axis sharding and must be replicated over the merge axes
-    only (the data-parallel path, not the FSDP path).
+    All remaining mesh axes stay on the compiler (shard_map's
+    ``axis_names`` names only the merge axes), which is what lets the same
+    step serve the implicit ``plan_train`` path; they must have size 1 (see
+    the refusal below), and params are replicated over the merge axes (the
+    data-parallel path, not the FSDP path).
     """
 
     def loss_fn(params, batch):
@@ -179,31 +206,34 @@ def make_train_step(model, cfg, optimizer, num_microbatches: int = 1,
         if defer_schedule is not None and not has_deferred:
             raise ValueError("defer_schedule given but the merge plan has "
                              "no :defer levels")
-        from jax.experimental.shard_map import shard_map
 
         axis = merge_axes_for(mesh, merge_topology)
         axes_set = set(axis) if isinstance(axis, tuple) else {axis}
-        auto = frozenset(mesh.axis_names) - axes_set
-        nontrivial_auto = [a for a in auto if mesh.shape[a] > 1]
+        nontrivial_auto = sorted(a for a in mesh.axis_names
+                                 if a not in axes_set and mesh.shape[a] > 1)
         if nontrivial_auto:
-            # Partial-auto shard_map over this repo's models (embedding
-            # gather under involuntary remat) aborts the pinned jax
-            # 0.4.37's SPMD partitioner with a *fatal* IsManualSubgroup
-            # check — fail loudly here instead of crashing the process.
+            # A shard_map manual over the merge axes only (partial auto)
+            # over this repo's models aborts the process inside XLA's SPMD
+            # partitioner on jax/jaxlib 0.9.0 (fatal check "Invalid binary
+            # instruction opcode copy", hlo_instruction.cc) — fail loudly
+            # here instead of crashing.
             raise NotImplementedError(
                 f"explicit hierarchical gradient merge needs the non-merge "
-                f"mesh axes to be trivial, but {sorted(nontrivial_auto)} "
-                f"have size > 1; XLA on jax 0.4.37 cannot partition this "
-                f"model under partial-auto shard_map (fatal "
-                f"IsManualSubgroup). Use a pure data-parallel mesh for the "
-                f"merge plan, or the implicit XLA reduction for "
+                f"mesh axes to be trivial, but {nontrivial_auto} have size "
+                f"> 1; XLA's SPMD partitioner aborts on this model under a "
+                f"partial-auto shard_map (fatal check: invalid binary "
+                f"instruction opcode copy). Use a pure data-parallel mesh "
+                f"for the merge plan, or the implicit XLA reduction for "
                 f"tensor-parallel cells.")
+        # every other axis has size 1: go manual over the whole mesh, so
+        # the partitioner never sees a partial-auto region
+        manual = frozenset(mesh.axis_names)
         grad_merge_fn = int8_compressed_add() if merge_compress else ADD
 
         if defer_schedule is not None:
             return _make_deferred_train_step(
                 grads_of, optimizer, mesh, merge_topology, merge_compress,
-                defer_schedule, axis, axes_set, auto, grad_merge_fn)
+                defer_schedule, axis, axes_set, manual, grad_merge_fn)
 
         def sharded_grads(params, batch):
             def shard_fn(params, batch):
@@ -217,10 +247,10 @@ def make_train_step(model, cfg, optimizer, num_microbatches: int = 1,
                                         compress=merge_compress)
                 return lax.pmean(loss, axis), grads
 
-            return shard_map(shard_fn, mesh=mesh,
-                             in_specs=(P(), P(axis)),
-                             out_specs=(P(), P()),
-                             check_rep=False, auto=auto)(params, batch)
+            return jax.shard_map(shard_fn, mesh=mesh,
+                                 in_specs=(P(), P(axis)),
+                                 out_specs=(P(), P()), axis_names=manual,
+                                 check_vma=False)(params, batch)
 
         grad_step = sharded_grads
     else:
@@ -373,8 +403,8 @@ class DeferredTrainStep:
 
 def _make_deferred_train_step(grads_of, optimizer, mesh: Mesh, plan,
                               merge_compress: bool,
-                              schedule: DeferSchedule, axis, axes_set, auto,
-                              grad_merge_fn) -> DeferredTrainStep:
+                              schedule: DeferSchedule, axis, axes_set,
+                              manual, grad_merge_fn) -> DeferredTrainStep:
     """The merge-on-evict train step family over ``defer_cascade``.
 
     Gradients are contributions to an ADD merge, so the pending cascade IS
@@ -392,7 +422,6 @@ def _make_deferred_train_step(grads_of, optimizer, mesh: Mesh, plan,
     step's own compute — independent values, so the scheduler overlaps
     them — and steps the optimizer on the landed cycle one step stale.
     """
-    from jax.experimental.shard_map import shard_map
 
     dp = 1
     for a in (axis if isinstance(axis, tuple) else (axis,)):
@@ -467,8 +496,9 @@ def _make_deferred_train_step(grads_of, optimizer, mesh: Mesh, plan,
         n_buf = n_def + (1 if overlap else 0)
         in_specs = (P(), P(axis)) + (P(axis),) * n_buf
         out_specs = (P(), P(axis), P()) if commits else (P(), P(axis))
-        sharded = shard_map(region, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False, auto=auto)
+        sharded = jax.shard_map(region, mesh=mesh, in_specs=in_specs,
+                                out_specs=out_specs, axis_names=manual,
+                                check_vma=False)
 
         def step(state, batch):
             params = state["params"]
@@ -512,8 +542,9 @@ def _make_deferred_train_step(grads_of, optimizer, mesh: Mesh, plan,
             local = jax.tree.map(lambda x: x[0], inflight)
             return ccache.settle_inflight(local, axis, grad_merge_fn, plan,
                                           compress=merge_compress)
-        return shard_map(region, mesh=mesh, in_specs=(P(axis),),
-                         out_specs=P(), check_rep=False, auto=auto)
+        return jax.shard_map(region, mesh=mesh, in_specs=(P(axis),),
+                             out_specs=P(), axis_names=manual,
+                             check_vma=False)
 
     def _partial_flush_program():
         def region(*pendings):
@@ -523,8 +554,9 @@ def _make_deferred_train_step(grads_of, optimizer, mesh: Mesh, plan,
                 zero, local, n_def, axis, grad_merge_fn, plan,
                 compress=merge_compress)
             return settled
-        return shard_map(region, mesh=mesh, in_specs=(P(axis),) * n_def,
-                         out_specs=P(), check_rep=False, auto=auto)
+        return jax.shard_map(region, mesh=mesh,
+                             in_specs=(P(axis),) * n_def, out_specs=P(),
+                             axis_names=manual, check_vma=False)
 
     def flush(state):
         d = state["defer"]
@@ -629,10 +661,9 @@ def plan_train(cfg, shape_cfg, mesh: Mesh,
     additionally takes a ``defer_schedule``; the state then carries the
     pending cascade (``state["defer"]``, leading-dim sharded over the merge
     axes) and the returned plan's ``defer_step`` holds every commit
-    variant. Restriction on the pinned jax
-    0.4.37: every non-merge mesh axis must have size 1 (pure data-parallel
-    meshes) — ``make_train_step`` raises on tensor-parallel cells, which
-    keep the implicit XLA reduction until the jax upgrade.
+    variant. Every non-merge mesh axis must have size 1 (pure data-parallel
+    meshes): ``make_train_step`` raises on tensor-parallel cells, which
+    keep the implicit XLA reduction.
     """
     model = build_model(cfg)
     rules = lowering_rules(cfg, shape_cfg, mesh)
@@ -646,18 +677,9 @@ def plan_train(cfg, shape_cfg, mesh: Mesh,
     opt_specs = jax.eval_shape(optimizer.init, param_specs)
 
     state_specs = {"params": param_specs, "opt": opt_specs}
-    params_sh = axes_to_shardings(param_axes, param_specs, mesh, rules)
-    opt_ax = opt_state_axes(opt_specs, param_axes)
-    opt_sh = OptState(
-        step=NamedSharding(mesh, P()),
-        mu=(None if opt_specs.mu is None
-            else axes_to_shardings(opt_ax.mu, opt_specs.mu, mesh, rules)),
-        nu=axes_to_shardings(opt_ax.nu, opt_specs.nu, mesh, rules))
-    state_sh = {"params": params_sh, "opt": opt_sh}
-
+    state_sh, batch_sh = train_shardings(model, shape_cfg, mesh, rules,
+                                         param_axes, param_specs, opt_specs)
     batch_specs = model.input_specs(shape_cfg)
-    batch_sh = axes_to_shardings(model.input_axes(shape_cfg), batch_specs,
-                                 mesh, rules)
 
     step = make_train_step(model, cfg, optimizer, nmb, mesh=mesh,
                            merge_topology=merge_plan,
@@ -674,17 +696,8 @@ def plan_train(cfg, shape_cfg, mesh: Mesh,
               else step.variants[-1])
         defer_specs = jax.eval_shape(step.init_defer_state, param_specs)
         state_specs["defer"] = defer_specs
-        axis = merge_axes_for(mesh, merge_plan)
-        defer_sh = {
-            "t": NamedSharding(mesh, P()),
-            "pending": jax.tree.map(
-                lambda _: NamedSharding(mesh, P(axis)),
-                defer_specs["pending"])}
-        if "inflight" in defer_specs:
-            defer_sh["inflight"] = jax.tree.map(
-                lambda _: NamedSharding(mesh, P(axis)),
-                defer_specs["inflight"])
-        state_sh["defer"] = defer_sh
+        state_sh["defer"] = defer_shardings(
+            defer_specs, mesh, merge_axes_for(mesh, merge_plan))
     metrics_sh = NamedSharding(mesh, P())
     out_sh = (state_sh, {"loss": metrics_sh, "grad_norm": metrics_sh,
                          "lr": metrics_sh})

@@ -52,12 +52,16 @@ if __name__ == "__main__":
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import checkpoint as ckpt
 from repro.configs.base import ShapeConfig, get_config, get_smoke_config
 from repro.data.pipeline import Prefetcher, data_config_for
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
-from repro.launch.steps import lowering_rules, make_train_step
+from repro.launch.steps import (defer_shardings, lowering_rules,
+                                make_train_step, merge_axes_for,
+                                train_shardings)
 from repro.models.module import split_params
 from repro.models.registry import build_model
 from repro.optim import make_optimizer, warmup_cosine
@@ -130,7 +134,9 @@ def solve_defer_for_cli(merge_defer: str, cfg, shape_cfg, mesh, topology,
     return schedule
 
 
-def main() -> None:
+def main(argv=None) -> list[dict]:
+    """Run the CLI; returns the driver's per-step events (step, loss, dt,
+    grad_norm)."""
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)
     p.add_argument("--smoke", action="store_true",
@@ -181,7 +187,8 @@ def main() -> None:
     p.add_argument("--mesh", choices=["host", "prod"], default="host")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--log", default=None)
-    args = p.parse_args()
+    args = p.parse_args(argv)
+    compile_cache.enable()
 
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_config(args.arch))
@@ -215,7 +222,6 @@ def main() -> None:
                                  axis_name="data")
     elif args.merge_topology:
         from repro.core.merge_plan import MergePlan
-        from repro.launch.steps import merge_axes_for
         try:
             topology = MergePlan.parse(
                 args.merge_topology,
@@ -268,13 +274,21 @@ def main() -> None:
                               defer_schedule=defer_schedule)
 
     with mesh, sharding_rules(mesh, rules):
-        params, _ = split_params(model.init(jax.random.key(args.seed)))
+        params, param_axes = split_params(
+            model.init(jax.random.key(args.seed)))
         state = {"params": params, "opt": optimizer.init(params)}
+        # state lives on the mesh by the logical rules and each batch is
+        # split over the data axes, so every device takes its share
+        state_sh, batch_sh = train_shardings(model, shape_cfg, mesh, rules,
+                                             param_axes, params, state["opt"])
         if defer_schedule is not None:
             state["defer"] = step_fn.init_defer_state(params)
-            jitted = step_fn.jit()
-        else:
-            jitted = jax.jit(step_fn)
+            state_sh["defer"] = defer_shardings(
+                state["defer"], mesh, merge_axes_for(mesh, topology))
+        shardings = dict(in_shardings=(state_sh, batch_sh),
+                         out_shardings=(state_sh, NamedSharding(mesh, P())))
+        jitted = (step_fn.jit(**shardings) if defer_schedule is not None
+                  else jax.jit(step_fn, **shardings))
 
         # Resume from the last committed checkpoint if present.
         start = 0
@@ -283,6 +297,7 @@ def main() -> None:
             state, extras = ckpt.restore(args.ckpt_dir, state)
             start = extras.get("next_step", last)
             print(f"resumed from checkpoint step {last} -> start {start}")
+        state = jax.device_put(state, state_sh)
 
         dcfg = data_config_for(cfg, shape_cfg, seed=args.seed)
         prefetch = Prefetcher(dcfg, start_step=start)
@@ -292,7 +307,7 @@ def main() -> None:
             DriverConfig(ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
                          log_path=args.log),
             step_fn=lambda s, b: jitted(s, b),
-            batch_fn=lambda i: prefetch.get()[1],
+            batch_fn=lambda i: jax.device_put(prefetch.get()[1], batch_sh),
             # deferred runs record the durability manifest next to each
             # boundary save so a restore under a changed plan/schedule can
             # settle the pendings (docs/fault_tolerance.md)
@@ -318,6 +333,7 @@ def main() -> None:
         if losses:
             print(f"steps {start}..{end}: loss {losses[0]['loss']:.4f} -> "
                   f"{losses[-1]['loss']:.4f}")
+        return losses
 
 
 if __name__ == "__main__":

@@ -23,19 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-
 from repro.models import moe as moe_base
 from repro.models.mlp import swiglu
 
@@ -143,5 +130,6 @@ def apply_ep(p, x: Array, top_k: int, capacity_factor: float, mesh,
     out_specs = (P(dp_spec, None, None),
                  {"aux_loss": P(), "router_z": P(), "drop_frac": P(),
                   "expert_load": P()})
-    f = shard_map(fn, mesh, in_specs, out_specs)
+    f = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)
     return f(x, p["router"]["w"], p["wi_gate"], p["wi_up"], p["wo"], shared)

@@ -213,6 +213,9 @@ class TrainDriver:
                 while True:
                     try:
                         new_state, metrics = self.step_fn(state, batch)
+                        # dispatch is asynchronous: the step ends when its
+                        # state is ready, so ``dt`` is the step's own time
+                        jax.block_until_ready(new_state)
                         break
                     except Exception as e:  # transient failure path
                         attempt += 1
@@ -250,8 +253,10 @@ class TrainDriver:
                     self._log({"event": "straggler", "step": step,
                                "dt": dt, "host": jax.process_index()})
                 self._step_times.append(dt)
-                self._log({"event": "step", "step": step, "loss": loss,
-                           "dt": dt})
+                rec = {"event": "step", "step": step, "loss": loss, "dt": dt}
+                if isinstance(metrics, dict) and "grad_norm" in metrics:
+                    rec["grad_norm"] = float(metrics["grad_norm"])
+                self._log(rec)
                 step += 1
 
                 boundary = (step % cfg.ckpt_every == 0) or self._preempted

@@ -11,10 +11,12 @@ private state, coherence is a scheduled event, not a per-access protocol.
 Two privatization engines, same algebra:
 
 * ``engine="kernel"`` — the production hot path.  A tick's updates scatter
-  into a merge-identity table (``apps.common.scatter``: the Pallas
-  ``cscatter`` kernel on real meshes, the jnp oracle under ``vmap``).  The
-  kernel's VMEM accumulator *is* the privatized copy — merged once per
-  block on grid exit with touched-mask dirty-merge skip.
+  into a merge-identity table: on a TPU backend through the compiled Pallas
+  ``cscatter`` kernel, whose VMEM accumulator *is* the privatized copy —
+  merged once per block on grid exit with touched-mask dirty-merge skip;
+  elsewhere through XLA's scatter (ADD) or the jnp oracle.  One shard is a
+  store too: its plan has no exchanging level, so it is synchronized and
+  every tick is a scatter into the settled table.
 * ``engine="blocked"`` — the faithful instrumented model.  A resident
   ``core.blocked.BlockedCache`` (W ways, LRU, merge-on-evict, dirty-merge
   skip) carries privatized blocks **across ticks**; only evicted mass
@@ -121,11 +123,6 @@ class KVConfig:
     # blocked engine: the paper's W-way source buffer geometry.
     ways: int = 8
     block_rows: int = 8
-    # kernel engine: scatter-phase kernel selection (Pallas needs a real
-    # mesh; the vmap executor must keep the jnp oracle).
-    use_pallas: bool = False
-    pallas_block_rows: Optional[int] = None
-    pallas_chunk: Optional[int] = None
     # partitioned settled table: every global row on exactly one home shard
     # (key % n_shards); pendings become a bounded ring (kernel engine) or
     # the blocked cache's spill-through-eviction buffer (module doc).
@@ -205,9 +202,8 @@ class ShardedKV:
                  plan: Optional[MergePlan] = None,
                  schedule: Optional[DeferSchedule] = None,
                  commit_every: Optional[int] = None):
-        if n_shards < 2:
-            raise ValueError("ShardedKV needs n_shards >= 2 (a single shard "
-                             "has nothing to reconcile)")
+        if n_shards < 1:
+            raise ValueError(f"ShardedKV needs n_shards >= 1, got {n_shards}")
         self.config = config
         self.n_shards = n_shards
         self.spmd = spmd
@@ -384,17 +380,15 @@ class ShardedKV:
         equals ``combine(pending, scatter(identity, ...))``)."""
         cfg = self.config
         kind = _KERNEL_KINDS[cfg.merge.xla_reduce]
-        if kind == "add" and not cfg.use_pallas:
+        on_tpu = jax.default_backend() == "tpu"
+        if kind == "add" and not on_tpu:
             # one-pass fused scatter-add: no identity table, no touched
             # mask — the oracle's passes cost full table sweeps
             ok = (keys >= 0) & (keys < cfg.n_keys)
             safe = jnp.where(ok, keys, 0).astype(jnp.int32)
             return table.at[safe].add(
                 jnp.where(ok[:, None], vals, jnp.zeros_like(vals)))
-        return scatter(table, keys, vals, kind=kind,
-                       use_pallas=cfg.use_pallas,
-                       block_rows=cfg.pallas_block_rows,
-                       chunk=cfg.pallas_chunk)
+        return scatter(table, keys, vals, kind=kind, use_pallas=on_tpu)
 
     def _scatter_delta(self, keys: Array, vals: Array) -> Array:
         """This tick's updates as a privatized delta table."""

@@ -133,10 +133,7 @@ def manual_axes(axes):
     the manual axes: a with_sharding_constraint naming them is rejected by
     jax. Model code doesn't know which axes the launch layer went manual
     over, so the explicit-merge train step installs this context and
-    ``logical_constraint`` suppresses every constraint while it is active
-    (on the pinned jax 0.4.37 even auto-axis constraints fatally abort the
-    SPMD partitioner — when a jax upgrade lifts that, this can relax to
-    masking only the manual axes out of resolved specs).
+    ``logical_constraint`` suppresses every constraint while it is active.
     """
     prev = _CTX.manual
     _CTX.manual = prev | frozenset(axes)
@@ -152,14 +149,12 @@ def logical_constraint(x: jax.Array, axes: tuple) -> jax.Array:
         return x
     if _CTX.manual:
         # No constraints inside a shard_map manual region: naming a manual
-        # axis is rejected outright, and on jax 0.4.37 even an auto-axis
-        # NamedSharding constraint trips the SPMD partitioner's
-        # IsManualSubgroup check. The auto axes' layout follows the operand
-        # shardings instead.
+        # axis is rejected outright, and the remaining axes are trivial
+        # there (launch.steps refuses partial-auto merges), so their layout
+        # follows the operand shardings.
         return x
     spec = spec_for(x.shape, axes, _CTX.mesh, _CTX.rules)
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(_CTX.mesh, spec))
+    return jax.lax.with_sharding_constraint(x, NamedSharding(_CTX.mesh, spec))
 
 
 def count_params(params: Any) -> int:

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core import compat
 
 PyTree = Any
 
@@ -40,7 +39,7 @@ def pipeline_apply(stage_fn: Callable[[PyTree, jax.Array], jax.Array],
     Returns [n_micro, mb, ...] — only stage S-1's copy holds the outputs.
     """
     s_idx = lax.axis_index(axis_name)
-    n_stages = compat.axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     n_micro = microbatches.shape[0]
     ticks = n_micro + n_stages - 1
     mb_shape = microbatches.shape[1:]

@@ -19,10 +19,7 @@ import os
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline fallback (tests/_hypothesis_stub.py)
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.runtime import DriverConfig, TrainDriver, chaos
 from repro.runtime.elastic import (effective_invariants,

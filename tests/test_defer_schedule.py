@@ -18,10 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline fallback (tests/_hypothesis_stub.py)
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import ccache
 from repro.core import merge_functions as mf
@@ -387,7 +384,7 @@ def test_train_step_defer_builds_variants():
     from jax.sharding import AbstractMesh
     from repro.launch.steps import DeferredTrainStep, make_train_step
     cfg, model, opt = _smoke_pieces()
-    mesh = AbstractMesh((("data", 8), ("model", 1)))
+    mesh = AbstractMesh((8, 1), ("data", "model"))
     plan = MergePlan.parse("chip:2,host:2,pod:2:defer")
     sched = DeferSchedule.fixed(3, ("pod",))
     step = make_train_step(model, cfg, opt, 1, mesh=mesh,
@@ -405,7 +402,7 @@ def test_train_step_defer_schedule_mismatch_raises():
     from jax.sharding import AbstractMesh
     from repro.launch.steps import make_train_step
     cfg, model, opt = _smoke_pieces()
-    mesh = AbstractMesh((("data", 8), ("model", 1)))
+    mesh = AbstractMesh((8, 1), ("data", "model"))
     plan = MergePlan.parse("chip:2,host:2,pod:2:defer")
     with pytest.raises(ValueError, match="do not match"):
         make_train_step(model, cfg, opt, 1, mesh=mesh, merge_topology=plan,
@@ -417,7 +414,7 @@ def test_train_step_schedule_without_defer_plan_raises():
     from jax.sharding import AbstractMesh
     from repro.launch.steps import make_train_step
     cfg, model, opt = _smoke_pieces()
-    mesh = AbstractMesh((("data", 8), ("model", 1)))
+    mesh = AbstractMesh((8, 1), ("data", "model"))
     with pytest.raises(ValueError, match="no :defer"):
         make_train_step(model, cfg, opt, 1, mesh=mesh,
                         merge_topology=MergePlan.parse("chip:4,pod:2"),
@@ -429,7 +426,7 @@ def test_plan_train_threads_defer_state():
     from repro.configs.base import ShapeConfig
     from repro.launch.steps import plan_train
     cfg, _, _ = _smoke_pieces()
-    mesh = AbstractMesh((("data", 8), ("model", 1)))
+    mesh = AbstractMesh((8, 1), ("data", "model"))
     shape = ShapeConfig("t", 32, 8, "train")
     lp = plan_train(cfg, shape, mesh,
                     merge_plan=MergePlan.parse("chip:2,host:2,pod:2:defer"),
@@ -506,7 +503,8 @@ def test_deferred_k1_matches_eager_explicit_train_path():
 
         cfg = get_smoke_config("xlstm_125m")
         shape = ShapeConfig("t", 32, 8, "train")
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(8, 1)
         rules = lowering_rules(cfg, shape, mesh)
         model = build_model(cfg)
         plan = MergePlan.parse("chip:2,host:2,pod:2:defer",

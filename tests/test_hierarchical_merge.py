@@ -13,7 +13,6 @@ import numpy as np
 import pytest
 
 from repro.core import ccache
-from repro.core import compat
 from repro.core import merge_functions as mf
 from repro.core.grad_merge import merge_gradients
 
@@ -166,7 +165,9 @@ def test_topology_validation():
 
 
 def test_compat_axis_size_under_vmap():
-    out = jax.vmap(lambda x: x * 0 + compat.axis_size("i"),
+    """The engine sizes its merge axis with ``lax.axis_size``, which must be
+    static under the vmap executor the fast tests use."""
+    out = jax.vmap(lambda x: x * 0 + jax.lax.axis_size("i"),
                    axis_name="i")(jnp.zeros(6))
     np.testing.assert_array_equal(np.asarray(out), np.full(6, 6.0))
 
@@ -174,15 +175,14 @@ def test_compat_axis_size_under_vmap():
 def test_hier_lowers_on_shard_map_mesh():
     """The shard_map lowering path (where the fused intra-group collective
     applies) at least compiles and runs on whatever devices exist."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_dev = jax.device_count()
     mesh = jax.make_mesh((n_dev,), ("dp",))
     topo = ccache.MergeTopology(group_size=n_dev)
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda u: ccache.hierarchical_merge(u, "dp", mf.ADD, topo),
-        mesh=mesh, in_specs=P("dp"), out_specs=P("dp"), check_rep=False))
+        mesh=mesh, in_specs=P("dp"), out_specs=P("dp"), check_vma=False))
     x = jnp.arange(n_dev * 4, dtype=jnp.float32).reshape(n_dev, 4)
     out = f(x)
     np.testing.assert_allclose(

@@ -13,6 +13,7 @@ import pytest
 
 from repro.configs.base import ShapeConfig, get_smoke_config
 from repro.data.pipeline import batch_at, data_config_for
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import (lowering_rules, make_train_step, plan_for)
 from repro.models.module import split_params
 from repro.models.registry import build_model
@@ -80,7 +81,7 @@ def test_checkpoint_resume_bit_identical():
 ])
 def test_plan_lowers_and_compiles_single_device(kind, shape):
     cfg = get_smoke_config("internlm2_1_8b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     plan = plan_for(cfg, shape, mesh)
     compiled = plan.lower(mesh).compile()
     assert compiled.cost_analysis() is not None

@@ -4,13 +4,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline fallback (tests/_hypothesis_stub.py)
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
-from repro.kernels.cscatter import cscatter
+from repro.kernels.cscatter import (VMEM_BUDGET, choose_tile, cscatter,
+                                     tile_bytes)
 
 
 TOL = {jnp.float32: 1e-5, jnp.bfloat16: 2e-2}
@@ -34,6 +32,37 @@ def test_cscatter_sweep(dtype, kind, r, d, n, br, ch):
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(gold, np.float32),
         rtol=TOL[dtype], atol=TOL[dtype] * 8)
+
+
+@pytest.mark.parametrize("kind", ["add", "max", "min", "or"])
+@pytest.mark.parametrize("r,n,d", [
+    (1 << 22, 1024, 4), (1 << 16, 327680, 1), (48, 160, 1), (1000, 7, 4),
+    (8191, 64, 2), (4096, 1024, 256)])
+def test_choose_tile_fits_budget(kind, r, n, d):
+    """The default tile never exceeds its VMEM budget, is the whole table
+    or a multiple of 8 rows, and its chunk is lane-aligned; a tile that
+    does not divide the table is the largest that fits (the table pads)."""
+    br, ch = choose_tile(kind, r, n, d)
+    assert ch % 128 == 0 and ch >= 128
+    assert br == r or br % 8 == 0
+    assert tile_bytes(kind, br, ch, d) <= VMEM_BUDGET
+    if r % br:
+        assert tile_bytes(kind, br + 8, ch, d) > VMEM_BUDGET
+
+
+def test_cscatter_default_tile_int_add_wraps_bitwise():
+    """int32 ADD through the byte-plane matmul equals ``.at[].add``
+    bitwise, wrap-around included, with the default tile and a table whose
+    row count has no tile divisor (8191 rows: padded internally)."""
+    rng = np.random.default_rng(0)
+    r, d, n = 8191, 4, 700
+    table = rng.integers(-2**31, 2**31, (r, d)).astype(np.int32)
+    ids = rng.integers(-3, r + 3, n).astype(np.int32)
+    vals = rng.integers(-2**31, 2**31, (n, d)).astype(np.int32)
+    out = cscatter(jnp.asarray(table), jnp.asarray(ids), jnp.asarray(vals))
+    ok = (ids >= 0) & (ids < r)
+    gold = jnp.asarray(table).at[ids[ok]].add(jnp.asarray(vals[ok]))
+    assert jnp.array_equal(out, gold)
 
 
 def test_cscatter_or_int():

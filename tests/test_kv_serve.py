@@ -21,10 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline fallback (tests/_hypothesis_stub.py)
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.defer_schedule import DeferSchedule
 from repro.core.merge_functions import MAX
@@ -241,7 +238,7 @@ def test_config_and_store_validation():
     with pytest.raises(ValueError, match="multiple"):
         KVConfig(n_keys=9, engine="blocked", block_rows=4)
     with pytest.raises(ValueError, match="n_shards"):
-        ShardedKV(KVConfig(n_keys=8), 1, _spmd)
+        ShardedKV(KVConfig(n_keys=8), 0, _spmd)
     # sync plan: a commit schedule is meaningless
     with pytest.raises(ValueError, match="deferred"):
         ShardedKV(KVConfig(n_keys=8), 4, _spmd,
@@ -259,6 +256,31 @@ def test_config_and_store_validation():
         ShardedKV(KVConfig(n_keys=8), 4, _spmd,
                   schedule=DeferSchedule.fixed(2, ("chip", "pod")),
                   commit_every=2)
+
+
+def test_one_shard_store_through_frontend_bitwise():
+    """One shard is a store: its plan exchanges nothing, so it is
+    synchronized, and adds through the frontend land bitwise — int32
+    wrap-around included — in both the gets and the table."""
+    R, D, B, T = 64, 4, 16, 5
+    store = ShardedKV(KVConfig(n_keys=R, cols=D), 1, _spmd)
+    assert store.synchronized and store.n_deferred == 0
+    fe = BatchedFrontend(store, slots_per_shard=B)
+    rng = np.random.default_rng(5)
+    keys = rng.integers(0, R, (T, B))
+    vals = rng.integers(-2**31, 2**31, (T, B, D)).astype(np.int32)
+    want = np.zeros((R, D), np.int64)
+    for t in range(T):
+        for k, v in zip(keys[t], vals[t]):
+            fe.add(int(k), v)
+        np.add.at(want, keys[t], vals[t].astype(np.int64))
+        fe.step()
+    want = want.astype(np.int32)  # wraps like the device's int32 adds
+    rids = {fe.get(k): k for k in range(R)}
+    got = fe.drain()
+    assert all(np.array_equal(got[r], want[k]) for r, k in rids.items())
+    store.flush()
+    assert np.array_equal(store.table(), want)
 
 
 def test_serving_plan_defer_knob():

@@ -15,10 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline fallback (tests/_hypothesis_stub.py)
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import ccache
 from repro.core import merge_functions as mf
@@ -478,7 +475,7 @@ def test_train_step_rejects_defer_plans():
     from repro.models.registry import build_model
     from repro.optim import adamw, constant
     cfg = get_smoke_config("xlstm_125m")
-    mesh = AbstractMesh((("data", 1), ("model", 1)))
+    mesh = AbstractMesh((1, 1), ("data", "model"))
     plan = MergePlan.parse("chip:1:defer")
     with pytest.raises(ValueError, match="defer"):
         make_train_step(build_model(cfg), cfg, adamw(constant(1e-3)), 1,
@@ -486,17 +483,17 @@ def test_train_step_rejects_defer_plans():
 
 
 def test_nontrivial_auto_axes_fail_loudly():
-    """Partial-auto shard_map would abort XLA 0.4.37 fatally; the step
-    builder must refuse with an explanation instead."""
+    """Partial-auto shard_map aborts XLA's SPMD partitioner fatally on this
+    model; the step builder must refuse with an explanation instead."""
     from jax.sharding import AbstractMesh
     from repro.launch.steps import make_train_step
     from repro.configs.base import get_smoke_config
     from repro.models.registry import build_model
     from repro.optim import adamw, constant
     cfg = get_smoke_config("xlstm_125m")
-    mesh = AbstractMesh((("data", 1), ("model", 2)))
+    mesh = AbstractMesh((1, 2), ("data", "model"))
     plan = MergePlan.parse("chip:1")
-    with pytest.raises(NotImplementedError, match="IsManualSubgroup"):
+    with pytest.raises(NotImplementedError, match="partial-auto"):
         make_train_step(build_model(cfg), cfg, adamw(constant(1e-3)), 1,
                         mesh=mesh, merge_topology=plan)
 
@@ -522,7 +519,8 @@ def test_three_level_plan_through_both_train_paths():
 
         cfg = get_smoke_config("xlstm_125m")
         shape = ShapeConfig("t", 32, 8, "train")
-        mesh = jax.make_mesh((2, 4, 1), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 4, 1), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         plan = MergePlan.parse("chip:2,host:2,pod:2", lane_parallel=True)
         dcfg = data_config_for(cfg, shape, seed=0)
         batch = jax.tree.map(jnp.asarray, batch_at(dcfg, 0))

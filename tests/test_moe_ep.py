@@ -29,7 +29,7 @@ SCRIPT = textwrap.dedent("""
         with mesh:
             o, _ = apply_ep(p, x, k, 8.0, mesh)
         return jnp.sum(o ** 2)
-    g = jax.grad(loss)(p)
+    g = jax.jit(jax.grad(loss))(p)
     assert all(bool(jnp.all(jnp.isfinite(v))) for v in jax.tree.leaves(g))
     print("EP_OK", err)
 """)

@@ -26,10 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline fallback (tests/_hypothesis_stub.py)
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import ccache
 from repro.core import merge_functions as mf
@@ -306,7 +303,7 @@ def test_train_step_overlap_builds_land_variants():
     from jax.sharding import AbstractMesh
     from repro.launch.steps import DeferredTrainStep, make_train_step
     cfg, model, opt = _smoke_pieces()
-    mesh = AbstractMesh((("data", 8), ("model", 1)))
+    mesh = AbstractMesh((8, 1), ("data", "model"))
     plan = MergePlan.parse("chip:2,host:2,pod:2:defer")
     sched = DeferSchedule.fixed(3, ("pod",), overlap=True)
     step = make_train_step(model, cfg, opt, 1, mesh=mesh,
@@ -327,7 +324,7 @@ def test_train_step_overlap_land_dispatch():
     from jax.sharding import AbstractMesh
     from repro.launch.steps import make_train_step
     cfg, model, opt = _smoke_pieces()
-    mesh = AbstractMesh((("data", 8), ("model", 1)))
+    mesh = AbstractMesh((8, 1), ("data", "model"))
     plan = MergePlan.parse("chip:2,host:2,pod:2:defer")
     step = make_train_step(
         model, cfg, opt, 1, mesh=mesh, merge_topology=plan,
@@ -349,7 +346,7 @@ def test_train_step_no_overlap_has_no_land_variants():
     from jax.sharding import AbstractMesh
     from repro.launch.steps import make_train_step
     cfg, model, opt = _smoke_pieces()
-    mesh = AbstractMesh((("data", 8), ("model", 1)))
+    mesh = AbstractMesh((8, 1), ("data", "model"))
     plan = MergePlan.parse("chip:2,host:2,pod:2:defer")
     step = make_train_step(
         model, cfg, opt, 1, mesh=mesh, merge_topology=plan,
@@ -367,7 +364,7 @@ def test_plan_train_threads_inflight_shardings():
     from repro.configs.base import ShapeConfig
     from repro.launch.steps import plan_train
     cfg, _, _ = _smoke_pieces()
-    mesh = AbstractMesh((("data", 8), ("model", 1)))
+    mesh = AbstractMesh((8, 1), ("data", "model"))
     shape = ShapeConfig("t", 32, 8, "train")
     lp = plan_train(
         cfg, shape, mesh,
@@ -441,7 +438,8 @@ def test_overlapped_train_path_equals_delayed_eager_reference():
         STEPS = 3
         cfg = get_smoke_config("xlstm_125m")
         shape = ShapeConfig("t", 32, 8, "train")
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(8, 1)
         rules = lowering_rules(cfg, shape, mesh)
         model = build_model(cfg)
         plan = MergePlan.parse("chip:2,host:2,pod:2:defer",
@@ -541,7 +539,8 @@ def test_train_path_flush_conserves_gradient_mass():
         K, STEPS = 2, 5  # two full cycles + a 1-step partial tail
         cfg = get_smoke_config("xlstm_125m")
         shape = ShapeConfig("t", 32, 8, "train")
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(8, 1)
         rules = lowering_rules(cfg, shape, mesh)
         model = build_model(cfg)
         plan = MergePlan.parse("chip:2,host:2,pod:2:defer",

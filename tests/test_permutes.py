@@ -8,10 +8,7 @@ where XOR pairing is assumed, blocks that do not tile the axis).
 """
 
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline fallback (tests/_hypothesis_stub.py)
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import permutes
 

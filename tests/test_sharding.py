@@ -5,13 +5,14 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_host_mesh
 from repro.sharding.partition import (DEFAULT_RULES, logical_constraint,
                                       sharding_rules, spec_for)
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_host_mesh(1, 1)
 
 
 class FakeMesh:

@@ -2,10 +2,7 @@
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline fallback (tests/_hypothesis_stub.py)
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from benchmarks.simulator import (ATOMIC, BARRIER, CREAD, CWRITE, MERGE,
                                   READ, WRITE, MachineConfig, run_trace)
