@@ -41,6 +41,9 @@ def mesh_spmd(mesh, axis_name: str = "shards"):
     ``spmd.lower(fn, *args, donate=())`` lowers the same program without
     running it; ``args`` may be shard-major ``ShapeDtypeStruct`` trees (HLO
     walks, memory checks).
+
+    Each program compiles under ``fn``'s name (``module @jit_<name>``), so
+    a profiler trace tells the programs apart.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -52,6 +55,8 @@ def mesh_spmd(mesh, axis_name: str = "shards"):
             out = fn(*loc)
             return jax.tree.map(lambda x: x[None], out)
 
+        region.__name__ = region.__qualname__ = getattr(
+            fn, "__name__", "region")
         sharded = jax.shard_map(
             region, mesh=mesh,
             in_specs=(P(axis_name),) * n_args,

@@ -26,6 +26,7 @@ from typing import Optional
 import numpy as np
 
 from repro.serve.kv import ShardedKV
+from repro.serve.spans import span
 
 
 class DrainBacklog(RuntimeError):
@@ -94,35 +95,41 @@ class BatchedFrontend:
         shard into a store tick, then up to ``slots`` head-of-queue gets
         per shard through a store read (FIFO per shard, see module doc).
         Always ticks (all-padding when idle) so the commit schedule
-        advances uniformly with wall-clock serving, not with load."""
-        S, B = self.store.n_shards, self.slots
-        D = self.store.config.cols
+        advances uniformly with wall-clock serving, not with load.
 
-        keys = np.full((S, B), -1, np.int32)
-        vals = np.broadcast_to(self._pad_val,
-                               (S, B, D)).copy()
-        for s in range(S):
-            for b in range(B):
-                if not self._q[s] or self._q[s][0][0] != "add":
-                    break
-                _, keys[s, b], vals[s, b] = self._q[s].popleft()
-        self.store.tick(keys, vals)
+        In a profiler trace the call is the span ``repro.frontend.step``,
+        holding ``repro.frontend.pack`` (the adds into the tick's batch),
+        the store's ``repro.kv.tick`` and, when a get is served,
+        ``repro.frontend.read``."""
+        with span("frontend.step"):
+            S, B = self.store.n_shards, self.slots
+            D = self.store.config.cols
 
-        rkeys = np.full((S, B), -1, np.int32)
-        rids = np.full((S, B), -1, np.int64)
-        any_get = False
-        for s in range(S):
-            for b in range(B):
-                if not self._q[s] or self._q[s][0][0] != "get":
-                    break
-                _, rids[s, b], rkeys[s, b] = self._q[s].popleft()
-                any_get = True
-        if not any_get:
-            return {}
-        out = np.asarray(self.store.read(rkeys))
-        return {int(rid): out[s, b]
-                for s in range(S) for b in range(B)
-                if (rid := rids[s, b]) >= 0}
+            with span("frontend.pack"):
+                keys = np.full((S, B), -1, np.int32)
+                vals = np.broadcast_to(self._pad_val, (S, B, D)).copy()
+                for s in range(S):
+                    for b in range(B):
+                        if not self._q[s] or self._q[s][0][0] != "add":
+                            break
+                        _, keys[s, b], vals[s, b] = self._q[s].popleft()
+            self.store.tick(keys, vals)
+
+            q = self._q
+            if not any(q[s] and q[s][0][0] == "get" for s in range(S)):
+                return {}
+            with span("frontend.read"):
+                rkeys = np.full((S, B), -1, np.int32)
+                rids = np.full((S, B), -1, np.int64)
+                for s in range(S):
+                    for b in range(B):
+                        if not q[s] or q[s][0][0] != "get":
+                            break
+                        _, rids[s, b], rkeys[s, b] = q[s].popleft()
+                out = np.asarray(self.store.read(rkeys))
+                return {int(rid): out[s, b]
+                        for s in range(S) for b in range(B)
+                        if (rid := rids[s, b]) >= 0}
 
     def drain(self, max_steps: Optional[int] = None, retries: int = 0,
               backoff_s: float = 0.0) -> dict[int, np.ndarray]:

@@ -70,6 +70,7 @@ from repro.core.defer_schedule import DeferSchedule
 from repro.core.merge_functions import ADD, MergeFn
 from repro.core.merge_plan import MergeLevel, MergePlan
 from repro.apps.common import default_plan, scatter
+from repro.serve.spans import span
 
 Array = jax.Array
 
@@ -80,6 +81,33 @@ _ENGINES = ("kernel", "blocked")
 _KERNEL_KINDS = {"add": "add", "max": "max", "min": "min", "or": "or"}
 
 DEFAULT_COMMIT_EVERY = 8
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` renamed: the mesh executor compiles it as the program
+    ``jit_<name>``, the name its runs carry in a profiler trace."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+def _scoped(scope: str) -> Callable:
+    """Trace the decorated function under ``jax.named_scope(scope)``: its
+    ops carry the scope in their HLO ``op_name`` metadata, which the trace
+    viewer shows as each op's scope path."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(scope):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
+def _read_program(fn: Callable, kind: str) -> Callable:
+    """A read closure as the program ``kv_read`` (``kind`` "plain") or
+    ``kv_read_<kind>``, its ops under the ``read`` scope."""
+    name = "kv_read" if kind == "plain" else f"kv_read_{kind}"
+    return _named(_scoped("read")(fn), name)
 
 
 def serving_plan(n_shards: int, defer: str = "all",
@@ -368,10 +396,12 @@ class ShardedKV:
     # per-shard program builders (closures created once, see class doc)
     # ------------------------------------------------------------------
 
+    @_scoped("identity")
     def _identity_table(self) -> Array:
         cfg = self.config
         return cfg.merge.identity((cfg.n_keys, cfg.cols), cfg.dtype)
 
+    @_scoped("scatter")
     def _scatter_into(self, table: Array, keys: Array, vals: Array) -> Array:
         """One shard's scatter phase: fold this tick's updates into
         ``table`` (the merge-identity table for a fresh delta, or the
@@ -407,18 +437,44 @@ class ShardedKV:
         return blocked.cop_scatter(cache, self._identity_table(), safe,
                                    vals, cfg.merge)
 
+    @_scoped("apply")
+    def _apply(self, settled: Array, agg: Array) -> Array:
+        return self.config.merge.apply(settled, agg)
+
+    @_scoped("settle")
+    def _cascade(self, delta: Array, pendings, due: int):
+        return ccache.defer_cascade(delta, list(pendings), due,
+                                    self.axis_name, self.config.merge,
+                                    self.plan)
+
+    @_scoped("settle")
+    def _settle(self, delta: Array) -> Array:
+        return ccache.settle_deferred(delta, self.axis_name,
+                                      self.config.merge, self.plan)
+
+    @_scoped("launch")
+    def _launch(self, delta: Array) -> Array:
+        return ccache.launch_inflight(delta, self.axis_name,
+                                      self.config.merge, self.plan)
+
+    @_scoped("land")
+    def _land(self, inflight: Array) -> Array:
+        return ccache.settle_inflight(inflight, self.axis_name,
+                                      self.config.merge, self.plan)
+
     def _make_sync_tick(self):
         merge, axis, plan = self.config.merge, self.axis_name, self.plan
 
         def sync_tick(settled, keys, vals):
             delta = self._scatter_delta(keys, vals)
-            full = ccache.hierarchical_merge(delta, axis, merge, plan)
-            return merge.apply(settled, full)
+            with jax.named_scope("settle"):
+                full = ccache.hierarchical_merge(delta, axis, merge, plan)
+            return self._apply(settled, full)
 
-        return sync_tick
+        return _named(sync_tick, "kv_tick_sync")
 
     def _make_deferred_tick(self, due: int):
-        merge, axis, plan = self.config.merge, self.axis_name, self.plan
+        merge = self.config.merge
         full = due == self.n_deferred
 
         if self.config.engine == "kernel" and self._fully_deferred:
@@ -427,19 +483,17 @@ class ShardedKV:
                 p0 = self._scatter_into(pendings[0], keys, vals)
                 if due == 0:
                     return settled, (p0,) + tuple(pendings[1:])
-                new_p, agg = ccache.defer_cascade(
-                    self._identity_table(), [p0] + list(pendings[1:]),
-                    due, axis, merge, plan)
+                new_p, agg = self._cascade(
+                    self._identity_table(), [p0] + list(pendings[1:]), due)
                 if full:
-                    settled = merge.apply(settled, agg)
+                    settled = self._apply(settled, agg)
                 return settled, tuple(new_p)
         elif self.config.engine == "kernel":
             def tick(settled, pendings, keys, vals):
                 delta = self._scatter_delta(keys, vals)
-                new_p, agg = ccache.defer_cascade(delta, list(pendings),
-                                                  due, axis, merge, plan)
+                new_p, agg = self._cascade(delta, pendings, due)
                 if full:
-                    settled = merge.apply(settled, agg)
+                    settled = self._apply(settled, agg)
                 return settled, tuple(new_p)
         else:
             def tick(settled, pendings, cache, keys, vals):
@@ -448,36 +502,35 @@ class ShardedKV:
                     # commit tick: the resident (unevicted) mass must
                     # enter the cascade too — the explicit merge instr.
                     cache, delta = blocked.flush(cache, delta, merge)
-                new_p, agg = ccache.defer_cascade(delta, list(pendings),
-                                                  due, axis, merge, plan)
+                new_p, agg = self._cascade(delta, pendings, due)
                 if full:
-                    settled = merge.apply(settled, agg)
+                    settled = self._apply(settled, agg)
                 return settled, tuple(new_p), cache
 
-        return tick
+        return _named(tick, f"kv_tick_commit_{due}" if due else
+                      "kv_tick_defer")
 
     def _make_flush(self):
-        merge, axis, plan = self.config.merge, self.axis_name, self.plan
+        merge = self.config.merge
         due = self.n_deferred
 
         if self.config.engine == "kernel":
             def flush_fn(settled, pendings):
-                new_p, agg = ccache.defer_cascade(
-                    self._identity_table(), list(pendings), due, axis,
-                    merge, plan)
-                return merge.apply(settled, agg), tuple(new_p)
+                new_p, agg = self._cascade(self._identity_table(), pendings,
+                                           due)
+                return self._apply(settled, agg), tuple(new_p)
         else:
             def flush_fn(settled, pendings, cache):
                 cache, delta = blocked.flush(cache, self._identity_table(),
                                              merge)
-                new_p, agg = ccache.defer_cascade(delta, list(pendings),
-                                                  due, axis, merge, plan)
-                return merge.apply(settled, agg), tuple(new_p), cache
+                new_p, agg = self._cascade(delta, pendings, due)
+                return self._apply(settled, agg), tuple(new_p), cache
 
-        return flush_fn
+        return _named(flush_fn, "kv_flush")
 
     # -- partitioned-mode builders (module doc: partitioned) ------------
 
+    @_scoped("home_rows")
     def _home_rows(self, agg: Array) -> Array:
         """This shard's home rows of a full ``(n_keys, cols)`` aggregate:
         global row ``r`` lives on shard ``r % S`` at local index
@@ -487,12 +540,14 @@ class ShardedKV:
         return agg.reshape(self.config.n_keys // S, S,
                            self.config.cols)[:, me, :]
 
+    @_scoped("ring_append")
     def _ring_append(self, ring, keys: Array, vals: Array):
         rk, rv, cur = ring
         rk = jax.lax.dynamic_update_slice_in_dim(rk, keys, cur, axis=0)
         rv = jax.lax.dynamic_update_slice_in_dim(rv, vals, cur, axis=0)
         return rk, rv, cur + keys.shape[0]
 
+    @_scoped("ring_reset")
     def _ring_reset(self, ring):
         rk, rv, cur = ring
         return (jnp.full_like(rk, -1),
@@ -525,7 +580,6 @@ class ShardedKV:
         return cache, spill, delta
 
     def _make_part_tick(self, full: bool, land: bool):
-        merge, axis, plan = self.config.merge, self.axis_name, self.plan
         overlap = self._overlap
 
         if self.config.engine == "kernel" and not land:
@@ -536,23 +590,21 @@ class ShardedKV:
                 delta = self._part_delta(ring)
                 ring = self._ring_reset(ring)
                 if overlap:
-                    return settled, ring, ccache.launch_inflight(
-                        delta, axis, merge, plan)
-                agg = ccache.settle_deferred(delta, axis, merge, plan)
-                return merge.apply(settled, self._home_rows(agg)), ring
+                    return settled, ring, self._launch(delta)
+                agg = self._settle(delta)
+                return self._apply(settled, self._home_rows(agg)), ring
         elif self.config.engine == "kernel":
             def tick(settled, ring, inflight, keys, vals):
                 ring = self._ring_append(ring, keys, vals)
                 # land the previous commit's launched aggregate: its top
                 # exchange overlaps this tick's scatter in one program
-                agg = ccache.settle_inflight(inflight, axis, merge, plan)
-                settled = merge.apply(settled, self._home_rows(agg))
+                agg = self._land(inflight)
+                settled = self._apply(settled, self._home_rows(agg))
                 if not full:
                     return settled, ring
                 delta = self._part_delta(ring)
                 ring = self._ring_reset(ring)
-                return settled, ring, ccache.launch_inflight(
-                    delta, axis, merge, plan)
+                return settled, ring, self._launch(delta)
         elif not land:
             def tick(settled, cache, spill, keys, vals):
                 cache, spill = self._spill_scatter(cache, spill, keys, vals)
@@ -560,30 +612,35 @@ class ShardedKV:
                     return settled, cache, spill
                 cache, spill, delta = self._part_drain_blocked(cache, spill)
                 if overlap:
-                    return settled, cache, spill, ccache.launch_inflight(
-                        delta, axis, merge, plan)
-                agg = ccache.settle_deferred(delta, axis, merge, plan)
-                return (merge.apply(settled, self._home_rows(agg)),
+                    return settled, cache, spill, self._launch(delta)
+                agg = self._settle(delta)
+                return (self._apply(settled, self._home_rows(agg)),
                         cache, spill)
         else:
             def tick(settled, cache, spill, inflight, keys, vals):
                 cache, spill = self._spill_scatter(cache, spill, keys, vals)
-                agg = ccache.settle_inflight(inflight, axis, merge, plan)
-                settled = merge.apply(settled, self._home_rows(agg))
+                agg = self._land(inflight)
+                settled = self._apply(settled, self._home_rows(agg))
                 if not full:
                     return settled, cache, spill
                 cache, spill, delta = self._part_drain_blocked(cache, spill)
-                return settled, cache, spill, ccache.launch_inflight(
-                    delta, axis, merge, plan)
+                return settled, cache, spill, self._launch(delta)
 
-        return tick
+        if land:
+            name = "kv_tick_land_launch" if full else "kv_tick_land"
+        elif full:
+            name = "kv_tick_launch" if overlap else "kv_tick_commit"
+        else:
+            name = "kv_tick_ring"
+        return _named(tick, name)
 
     def _make_part_flush(self, land: bool):
-        merge, axis, plan = self.config.merge, self.axis_name, self.plan
-
         def settle_home(settled, delta):
-            agg = ccache.settle_deferred(delta, axis, merge, plan)
-            return merge.apply(settled, self._home_rows(agg))
+            agg = self._settle(delta)
+            return self._apply(settled, self._home_rows(agg))
+
+        def land_home(settled, inflight):
+            return self._apply(settled, self._home_rows(self._land(inflight)))
 
         if self.config.engine == "kernel" and not land:
             def flush_fn(settled, ring):
@@ -591,8 +648,7 @@ class ShardedKV:
                 return settled, self._ring_reset(ring)
         elif self.config.engine == "kernel":
             def flush_fn(settled, ring, inflight):
-                agg = ccache.settle_inflight(inflight, axis, merge, plan)
-                settled = merge.apply(settled, self._home_rows(agg))
+                settled = land_home(settled, inflight)
                 settled = settle_home(settled, self._part_delta(ring))
                 return settled, self._ring_reset(ring)
         elif not land:
@@ -601,12 +657,11 @@ class ShardedKV:
                 return settle_home(settled, delta), cache, spill
         else:
             def flush_fn(settled, cache, spill, inflight):
-                agg = ccache.settle_inflight(inflight, axis, merge, plan)
-                settled = merge.apply(settled, self._home_rows(agg))
+                settled = land_home(settled, inflight)
                 cache, spill, delta = self._part_drain_blocked(cache, spill)
                 return settle_home(settled, delta), cache, spill
 
-        return flush_fn
+        return _named(flush_fn, "kv_flush_land" if land else "kv_flush")
 
     def _make_part_read(self, kind: str):
         cfg = self.config
@@ -626,7 +681,7 @@ class ShardedKV:
         if kind == "plain":
             def read(settled, keys):
                 return base_gather(settled, keys)[0]
-            return read
+            return _read_program(read, kind)
 
         def ring_overlay(ring, keys, ok):
             # the device's own buffered updates for each key, reduced with
@@ -677,7 +732,7 @@ class ShardedKV:
                     base, ok = base_gather(settled, keys)
                     return merge.apply(base,
                                        cache_overlay(cache, spill, keys, ok))
-            return read
+            return _read_program(read, kind)
 
         if kind != "ryw_inflight":
             raise ValueError(f"unknown partitioned read kind {kind!r}")
@@ -692,7 +747,7 @@ class ShardedKV:
                 base = inflight_overlay(base, inflight, keys, ok)
                 return merge.apply(base,
                                    cache_overlay(cache, spill, keys, ok))
-        return read
+        return _read_program(read, kind)
 
     def _make_read(self):
         cfg = self.config
@@ -709,7 +764,7 @@ class ShardedKV:
         if not ryw:
             def read(settled, keys):
                 return gather(settled, keys)
-            return read
+            return _read_program(read, "plain")
 
         if cfg.engine == "kernel":
             def read(settled, pendings, keys):
@@ -717,7 +772,7 @@ class ShardedKV:
                 for p in pendings:
                     view = merge.apply(view, p)
                 return gather(view, keys)
-            return read
+            return _read_program(read, "ryw")
 
         def read(settled, pendings, cache, keys):
             view = settled
@@ -740,7 +795,7 @@ class ShardedKV:
             ident = merge.identity(res.shape, res.dtype)
             return merge.apply(base, jnp.where(hit[:, None], res, ident))
 
-        return read
+        return _read_program(read, "ryw")
 
     # ------------------------------------------------------------------
     # host-side driver API
@@ -755,18 +810,32 @@ class ShardedKV:
         """Ingest one fixed-shape batch of updates: ``keys`` [S, B] int32
         (< 0 = padding), ``vals`` [S, B, cols].  Commit policy rides the
         schedule; non-commit ticks of a fully deferred plan run zero
-        collectives."""
-        if not self.synchronized and hasattr(self.schedule, "observe"):
-            # adaptive schedule: feed the real (non-padding) ingest count
-            # into the EMA before the boundary re-solve can fire
-            self.schedule.observe(int((np.asarray(keys) >= 0).sum()))
-        keys = jnp.asarray(keys, jnp.int32)
-        vals = jnp.asarray(vals, self.config.dtype)
-        if self._journal is not None and not self._replaying:
-            # Write-ahead: the batch is on disk before any device work, so
-            # a crash at ANY later point in this tick is recoverable —
-            # tick() returning is the acknowledgement point.
-            self._journal.append(keys, vals)
+        collectives.
+
+        In a profiler trace the call is the span ``repro.kv.tick``, split
+        into ``repro.kv.stage`` (the batch onto the device),
+        ``repro.kv.journal`` (with a journal attached) and
+        ``repro.kv.dispatch`` (the program's launch)."""
+        with span("kv.tick"):
+            with span("kv.stage"):
+                if not self.synchronized and hasattr(self.schedule,
+                                                     "observe"):
+                    # adaptive schedule: feed the real (non-padding)
+                    # ingest count into the EMA before the boundary
+                    # re-solve can fire
+                    self.schedule.observe(int((np.asarray(keys) >= 0).sum()))
+                keys = jnp.asarray(keys, jnp.int32)
+                vals = jnp.asarray(vals, self.config.dtype)
+            if self._journal is not None and not self._replaying:
+                # Write-ahead: the batch is on disk before any device work,
+                # so a crash at ANY later point in this tick is recoverable
+                # — tick() returning is the acknowledgement point.
+                with span("kv.journal"):
+                    self._journal.append(keys, vals)
+            with span("kv.dispatch"):
+                self._dispatch(keys, vals)
+
+    def _dispatch(self, keys: Array, vals: Array) -> None:
         if self.synchronized:
             self.settled = self._run(self._tick_fns["sync"], self.settled,
                                      keys, vals, donate=(0,))
@@ -853,17 +922,19 @@ class ShardedKV:
         """Serve one fixed-shape batch of gets: ``keys`` [S, B] -> [S, B,
         cols].  Zero collectives either way: ``eventual`` reads the last
         settled table; ``read_your_writes`` overlays the device's own
-        unmerged pendings (+ resident cache delta, blocked engine)."""
-        keys = jnp.asarray(keys, jnp.int32)
-        if self.partitioned:
-            return self._read_partitioned(keys)
-        if self.synchronized or self.config.consistency == "eventual":
-            return self.spmd(self._read_fn, self.settled, keys)
-        if self.config.engine == "kernel":
+        unmerged pendings (+ resident cache delta, blocked engine).
+        The span ``repro.kv.read`` in a profiler trace."""
+        with span("kv.read"):
+            keys = jnp.asarray(keys, jnp.int32)
+            if self.partitioned:
+                return self._read_partitioned(keys)
+            if self.synchronized or self.config.consistency == "eventual":
+                return self.spmd(self._read_fn, self.settled, keys)
+            if self.config.engine == "kernel":
+                return self.spmd(self._read_fn, self.settled, self.pendings,
+                                 keys)
             return self.spmd(self._read_fn, self.settled, self.pendings,
-                             keys)
-        return self.spmd(self._read_fn, self.settled, self.pendings,
-                         self.cache, keys)
+                             self.cache, keys)
 
     def _read_partitioned(self, keys: Array) -> Array:
         kernel = self.config.engine == "kernel"
@@ -884,18 +955,21 @@ class ShardedKV:
 
         After a flush the settled table equals the fully-synchronized
         reference over the same update stream — bitwise, for integer ADD.
-        Resets the schedule phase (a flush ends the current cycle)."""
+        Resets the schedule phase (a flush ends the current cycle). The
+        span ``repro.kv.flush`` in a profiler trace."""
         if self.synchronized:
             return
-        if self.partitioned:
-            self._flush_partitioned()
-        elif self.config.engine == "kernel":
-            self.settled, self.pendings = self._run(
-                self._flush_fn, self.settled, self.pendings, donate=(0, 1))
-        else:
-            self.settled, self.pendings, self.cache = self._run(
-                self._flush_fn, self.settled, self.pendings, self.cache,
-                donate=(0, 1, 2))
+        with span("kv.flush"):
+            if self.partitioned:
+                self._flush_partitioned()
+            elif self.config.engine == "kernel":
+                self.settled, self.pendings = self._run(
+                    self._flush_fn, self.settled, self.pendings,
+                    donate=(0, 1))
+            else:
+                self.settled, self.pendings, self.cache = self._run(
+                    self._flush_fn, self.settled, self.pendings, self.cache,
+                    donate=(0, 1, 2))
         self._t = 0
         if hasattr(self.schedule, "reset"):
             self.schedule.reset()
