@@ -13,7 +13,8 @@ Two privatization engines, same algebra:
 * ``engine="kernel"`` — the production hot path.  A tick's updates scatter
   into a merge-identity table: on a TPU backend through the compiled Pallas
   ``cscatter`` kernel, whose VMEM accumulator *is* the privatized copy —
-  merged once per block on grid exit with touched-mask dirty-merge skip;
+  merged once per touched block, in place, with touched-mask dirty-merge
+  skip;
   elsewhere through XLA's scatter (ADD) or the jnp oracle.  One shard is a
   store too: its plan has no exchanging level, so it is synchronized and
   every tick is a scatter into the settled table.
@@ -255,7 +256,8 @@ class ShardedKV:
         self.synchronized = self.n_deferred == 0
         # fully deferred (no eager stages): a non-commit tick has no
         # exchange at all, so updates coalesce straight into the resident
-        # pending — the merge-on-evict hot path, one table pass per tick
+        # pending — the merge-on-evict hot path, one pass over the touched
+        # blocks per tick
         self._fully_deferred = len(all_stages) == self.n_deferred > 0
         if self.synchronized:
             if schedule is not None or commit_every is not None:

@@ -8,7 +8,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.cscatter import (VMEM_BUDGET, choose_tile, cscatter,
-                                     tile_bytes)
+                                     tile_bytes, visits)
 
 
 TOL = {jnp.float32: 1e-5, jnp.bfloat16: 2e-2}
@@ -112,6 +112,114 @@ def test_cscatter_untouched_rows_bit_exact():
                    chunk=3, sat_min=-0.5, sat_max=0.5)
     mask = jnp.zeros((64,), bool).at[jnp.asarray([3, 5])].set(True)
     assert jnp.array_equal(out[~mask], table[~mask])  # dirty-merge skip
+
+
+# ------------------------------------------------- cscatter work list
+
+WORK_LIST_CASES = ["all_invalid", "every_block", "one_block_many_chunks",
+                   "unsorted_duplicates", "ragged_table"]
+
+
+def _work_list_case(case, kind, rng):
+    """``(table, ids, vals, block_rows, chunk)``: float32 for ``add`` and
+    ``sat_add`` (saturating at +-2), full-range int32 for ``int_add`` (sums
+    wrap) and the other kinds."""
+    r, br, ch = 64, 16, 16
+    if case == "all_invalid":
+        ids = np.concatenate([rng.integers(-5, 0, 20),
+                              rng.integers(r, r + 6, 20)])
+    elif case == "every_block":
+        br = 8
+        ids = np.concatenate([rng.permutation(r), rng.integers(0, r, 64)])
+    elif case == "one_block_many_chunks":
+        # 64 updates of row 37 (block 2) among others: in the sorted
+        # stream they span at least four chunks
+        ids = np.concatenate([np.full(64, 37), rng.integers(0, r, 20)])
+    elif case == "unsorted_duplicates":
+        br = 8
+        ids = rng.integers(0, 8, 50) * 8 + 3
+    else:  # ragged_table: 70 rows in blocks of 16
+        r = 70
+        ids = rng.integers(-3, r + 3, 80)
+    rng.shuffle(ids)
+    n = len(ids)
+    if kind in ("add", "sat_add"):
+        table = rng.uniform(-1.9, 1.9, (r, 4)).astype(np.float32)
+        vals = rng.normal(0, 0.5, (n, 4)).astype(np.float32)
+        if case == "one_block_many_chunks":
+            # row 37's updates sum to zero: +0.5 in its first 32, -0.5
+            # after; a merge per chunk would saturate on the way
+            table[37] = 1.5
+            hits = np.flatnonzero(ids == 37)
+            vals[hits[:32]], vals[hits[32:]] = 0.5, -0.5
+    else:
+        table = rng.integers(-2**31, 2**31, (r, 4), dtype=np.int64)
+        vals = rng.integers(-2**31, 2**31, (n, 4), dtype=np.int64)
+        table, vals = table.astype(np.int32), vals.astype(np.int32)
+    return (jnp.asarray(table), jnp.asarray(ids.astype(np.int32)),
+            jnp.asarray(vals), br, ch)
+
+
+@pytest.mark.parametrize("kind", ["add", "sat_add", "int_add", "max", "min",
+                                  "or"])
+@pytest.mark.parametrize("case", WORK_LIST_CASES)
+def test_cscatter_work_list_matches_serialization(case, kind):
+    """The work-list grid equals the literal serialization, and every row
+    of a block that no valid id touches is bitwise the input's (the block
+    is never visited, the table updated in place)."""
+    table, ids, vals, br, ch = _work_list_case(
+        case, kind, np.random.default_rng(WORK_LIST_CASES.index(case)))
+    kind = "add" if kind == "int_add" else kind
+    out = cscatter(table, ids, vals, kind=kind, block_rows=br, chunk=ch,
+                   sat_min=-2.0, sat_max=2.0)
+    gold = ref.ref_cscatter_serial(table, ids, vals, kind, -2.0, 2.0)
+    if jnp.issubdtype(table.dtype, jnp.floating):
+        np.testing.assert_allclose(np.asarray(out), np.asarray(gold),
+                                   rtol=TOL[jnp.float32],
+                                   atol=TOL[jnp.float32] * 8)
+    else:
+        assert jnp.array_equal(out, gold)
+    r = table.shape[0]
+    ok = np.asarray((ids >= 0) & (ids < r))
+    hit = np.zeros(-(-r // br), bool)
+    hit[np.asarray(ids)[ok] // br] = True
+    cold = ~np.repeat(hit, br)[:r]
+    assert jnp.array_equal(out[cold], table[cold])
+    if case == "all_invalid":
+        assert jnp.array_equal(out, table)
+    if case == "one_block_many_chunks":
+        pos = np.flatnonzero(np.sort(np.asarray(ids)) // br == 37 // br)
+        assert len(np.unique(pos // ch)) >= 3
+
+
+@pytest.mark.parametrize("r,n,kind", [
+    (1 << 16, 100, "add"),     # sparse: one chunk, ~100 blocks
+    (1 << 18, 300, "add"),     # sparse: three chunks
+    (1 << 16, 3000, "max"),    # dense: every block, the serial fold
+    (64, 1000, "add"),         # one block: the chunk sweep
+])
+def test_cscatter_visits_counts_items(r, n, kind):
+    """``visits`` is the number of non-empty (block, chunk) items of the
+    sorted stream at the default tile (every chunk for a one-block table),
+    and the kernel at that tile matches the serialization."""
+    rng = np.random.default_rng(n)
+    ids = rng.integers(-2, r + 2, n).astype(np.int32)
+    br, ch = choose_tile(kind, r, n, 4)
+    br = min(br, r)
+    if br == r:
+        expected = -(-n // ch)
+    else:
+        valid = np.sort(ids[(ids >= 0) & (ids < r)])
+        pos = np.arange(len(valid))
+        expected = len(set(zip(pos // ch, valid // br)))
+    assert int(visits(jnp.asarray(ids), r, 4, kind)) == expected
+    table = jnp.asarray(rng.integers(-2**31, 2**31, (r, 4), dtype=np.int64)
+                        .astype(np.int32))
+    vals = jnp.asarray(rng.integers(-2**31, 2**31, (n, 4), dtype=np.int64)
+                       .astype(np.int32))
+    out = cscatter(table, jnp.asarray(ids), vals, kind=kind)
+    assert jnp.array_equal(
+        out, ref.ref_cscatter_serial(table, jnp.asarray(ids), vals, kind))
 
 
 # ---------------------------------------------------------------- cmerge

@@ -10,15 +10,19 @@ cannot be read back here.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.kernels.cscatter import cscatter
 
 ROWS, COLS, BATCH = 1 << 22, 4, 1024
+# the benchmark cells' table: 2^23 x 4 int32 counters a chip
+STORE_ROWS = 1 << 23
 
 
 @pytest.fixture(scope="module")
@@ -66,3 +70,53 @@ def test_cscatter_compiles_for_v5e(one_chip, no_persistent_cache, kind,
         lambda t, i, v: cscatter(t, i, v, kind=kind, interpret=False)
     ).lower(table, ids, vals).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("batch", [1024, 8192])
+def test_cscatter_updates_table_in_place_for_v5e(one_chip, no_persistent_cache,
+                                                 batch):
+    """At the cells' shapes (a tick's 1,024 ids, a commit ring's 8,192) the
+    kernel's custom call aliases its table operand to its output: blocks
+    no id touches are neither read nor written."""
+    table = jax.ShapeDtypeStruct((STORE_ROWS, COLS), jnp.int32,
+                                 sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip)
+    vals = jax.ShapeDtypeStruct((batch, COLS), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda t, i, v: cscatter(t, i, v, interpret=False), donate_argnums=0
+    ).lower(table, ids, vals).compile()
+    (call,) = [line for line in compiled.as_text().splitlines()
+               if "tpu_custom_call" in line]
+    # operands: item blocks, item positions, item count, ids, vals, table
+    assert "output_to_operand_aliasing={{}: (5, {})}" in call
+
+
+def test_store_sync_tick_copies_no_more_tables_for_v5e(
+        topo, no_persistent_cache, monkeypatch):
+    """The one-chip store's tick (2^23 keys, 1,024 updates) holds at most
+    two table-sized copies, the relayouts of the settled table into the
+    kernel's 128-lane layout and back, and no third: the kernel updates
+    the identity table it is given in place."""
+    from repro.apps.sharded import mesh_spmd
+    from repro.core.merge_functions import ADD
+    from repro.serve import KVConfig, ShardedKV, serving_plan
+
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("shards",))
+    store = ShardedKV(
+        KVConfig(n_keys=STORE_ROWS, cols=COLS, dtype=jnp.int32, merge=ADD,
+                 consistency="eventual", engine="kernel"),
+        1, mesh_spmd(mesh), plan=serving_plan(1, "all"))
+    shard = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec(
+        "shards"))
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct((1,) + s.shape, s.dtype,
+                                       sharding=shard),
+        store.tick_arg_specs(BATCH))
+    # the store picks the Pallas kernel on a TPU backend only
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo = store.spmd.lower(store.raw_tick_fn(), *args,
+                           donate=(0,)).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    copies = re.findall(
+        rf"= s32\[(?:1,)?{STORE_ROWS},{COLS}\]\S* copy(?:-start)?\(", hlo)
+    assert len(copies) <= 2, copies
