@@ -43,6 +43,9 @@ _SCALES = (1.0, 10.0, 100.0)
 _PROBE_SCALARS = (2.0, 0.5, 3.0)
 _N_TRIALS = 3
 
+# merges over bit patterns: probed on int32 words, not floats
+_BITWISE = ("or", "and", "xor")
+
 # apply-side primitives that read memory through a value-dependent branch.
 _MEMORY_OBSERVING = {"min", "max", "clamp", "select_n",
                      "lt", "gt", "le", "ge"}
@@ -52,11 +55,11 @@ def _sample(fn: MergeFn, rng: np.random.Generator, shape: tuple,
             scale: float):
     """A random update drawn from the merge's value domain.
 
-    Bitwise merges (or/and) get int32 bit patterns; everything else gets
+    Bitwise merges (or/and/xor) get int32 bit patterns; everything else gets
     floats bounded away from zero (MUL/COMPLEX_MUL deltas divide) with
     random signs, scaled by the magnitude-sweep factor.
     """
-    if fn.xla_reduce in ("or", "and"):
+    if fn.xla_reduce in _BITWISE:
         return jnp.asarray(rng.integers(0, 1 << 20, size=shape), jnp.int32)
     mag = rng.uniform(0.5, 1.5, size=shape) * scale
     sign = rng.choice([-1.0, 1.0], size=shape)
@@ -185,7 +188,7 @@ def certify_merge_fn(fn: MergeFn, site: Optional[str] = None,
         # comparison primitives combine never uses) contradicts the
         # homomorphism even when the probes missed the threshold.
         spec = jax.ShapeDtypeStruct(
-            shape, jnp.int32 if fn.xla_reduce in ("or", "and")
+            shape, jnp.int32 if fn.xla_reduce in _BITWISE
             else jnp.float32)
         try:
             apply_prims = _primitive_names(

@@ -45,6 +45,7 @@ from repro.core.merge_functions import (
     ADD,
     BITWISE_AND,
     BITWISE_OR,
+    BITWISE_XOR,
     COMPLEX_MUL,
     MAX,
     MIN,

@@ -456,20 +456,26 @@ def _run_stages(update: PyTree, axis_name, merge: MergeFn,
     """Execute compiled stages in order. Invariant: entering stage i every
     rank holds its stride-sized unit's combination (replicated within the
     unit); leaving it, its block's. After the last stage every rank holds
-    the full combination over the covered levels."""
+    the full combination over the covered levels. Each stage's exchange,
+    the fused collective or the software butterfly, runs under
+    ``jax.named_scope("exchange")``, which its ops carry in their HLO
+    ``op_name``."""
     u = update
     rank = None
     if any(s.stride > 1 for s in stages):
         rank = lax.axis_index(axis_name)
     for st in stages:
         use_compress = st.compress and merge.encode is not None
-        if st.stride == 1:
-            u = _stage_innermost(u, axis_name, merge, st, size, force_tree,
-                                 use_compress)
-        elif st.lane_parallel:
-            u = _stage_lane(u, axis_name, merge, st, size, rank, use_compress)
-        else:
-            u = _stage_rep(u, axis_name, merge, st, size, rank, use_compress)
+        with jax.named_scope("exchange"):
+            if st.stride == 1:
+                u = _stage_innermost(u, axis_name, merge, st, size,
+                                     force_tree, use_compress)
+            elif st.lane_parallel:
+                u = _stage_lane(u, axis_name, merge, st, size, rank,
+                                use_compress)
+            else:
+                u = _stage_rep(u, axis_name, merge, st, size, rank,
+                               use_compress)
     return u
 
 
@@ -946,11 +952,6 @@ def reduce_update(update: PyTree, axis_name, merge: MergeFn,
         return jax.tree.map(
             functools.partial(_XLA_REDUCERS[merge.xla_reduce], axis_name=axis_name),
             update)
-    if not force_tree and merge.xla_reduce in ("or", "and"):
-        # XLA lowers integer min/max/sum but not or/and directly through the
-        # jax API; or/and over uint can be expressed via max/min for bitmaps
-        # only in the 1-bit case, so take the tree path for full generality.
-        return tree_merge(update, axis_name, merge)
     return tree_merge(update, axis_name, merge)
 
 

@@ -15,10 +15,13 @@ locally coalesce updates (soft_merge):
 value-dependent conditionals (e.g. saturation thresholds) observe memory, not
 the update copy.
 
-``xla_reduce`` names XLA's fused collective combiner when ``combine`` is one of
-the fixed ops (add/mul/min/max/or/and). That fast path is the moral equivalent
-of COUP: fixed ops fused into the "protocol". Anything else runs on the CCache
-flexible path (tree ppermute merge).
+``xla_reduce`` names the fixed op ``combine`` is (add/mul/min/max/or/and/xor),
+which the scatter kernel and XLA's fused collectives know by that name. Where
+jax has a fused collective for it (psum/pmax/pmin), the exchange rides it:
+the moral equivalent of COUP, fixed ops fused into the "protocol". Anything
+else (or/and/xor, whose collectives jax lacks, and every merge without a fixed
+op) runs on the CCache flexible path (a ppermute butterfly whose combine is
+the merge's own function).
 
 ``encode``/``decode`` optionally compress the update for the wire (gradient
 compression as a delta-merge property; beyond-paper, see DESIGN.md §3).
@@ -39,9 +42,10 @@ the engine checks at plan-compile / schedule-solve time (instead of the old
                  semantics exist (divide one settled sum by the number of
                  contributions) — ADD and its compressed variants.
     invertible   every update has an inverse under combine (ADD/MUL/
-                 COMPLEX_MUL). Lets clients subtract their own contribution
-                 from a settled aggregate (e.g. remote-mass extraction in
-                 the sharded PageRank app).
+                 COMPLEX_MUL; XOR, whose every update is its own). Lets
+                 clients subtract their own contribution from a settled
+                 aggregate (e.g. remote-mass extraction in the sharded
+                 PageRank app).
     deferrable   apply is a homomorphism over combine:
                  apply(apply(m, u1), u2) == apply(m, combine(u1, u2)).
                  False when apply observes memory between commits
@@ -75,7 +79,7 @@ class MergeFn:
     combine: Callable[[Array, Array], Array]
     apply: Callable[..., Array]  # (mem, u, *, key=None) -> mem'
     identity: Callable[[tuple, Any], Array]
-    xla_reduce: Optional[str] = None  # {"add","mul","min","max","or","and"}
+    xla_reduce: Optional[str] = None  # {"add","mul","min","max","or","and","xor"}
     encode: Optional[Callable[[Array], PyTree]] = None
     decode: Optional[Callable[[PyTree], Array]] = None
     needs_key: bool = False  # apply wants a PRNG key (approximate merges)
@@ -279,6 +283,19 @@ BITWISE_AND = MergeFn(
 )
 
 
+BITWISE_XOR = MergeFn(  # HPCC RandomAccess's update: word ^= value
+    name="xor",
+    delta=lambda src, upd: upd ^ src,
+    combine=lambda a, b: a ^ b,
+    apply=lambda mem, u: mem ^ u,
+    identity=_zeros,
+    xla_reduce="xor",
+    # a ^ a == 0: neither idempotent nor scalable, so a one-step-stale
+    # (overlapped) landing is refused; deferred commits are exact
+    invertible=True,
+)
+
+
 def saturating_add(max_value: float, min_value: float | None = None) -> MergeFn:
     """Paper §4.5 / §6.3: additive merge with a memory-observed threshold."""
 
@@ -400,7 +417,7 @@ class MergeFunctionRegistry:
 
 def default_registry() -> MergeFunctionRegistry:
     reg = MergeFunctionRegistry()
-    for fn in (ADD, MAX, MIN, BITWISE_OR, MUL, COMPLEX_MUL):
+    for fn in (ADD, MAX, MIN, BITWISE_OR, MUL, COMPLEX_MUL, BITWISE_XOR):
         reg.merge_init(fn)
     return reg
 

@@ -35,9 +35,9 @@ TPU mapping of the paper's hardware:
   plane sums stay below 2**24 and so are exact in f32, and the planes
   recombine with int32 shifts — bitwise equal to ``.at[].add``, wrap-around
   included.
-* MAX/MIN/OR have no MXU form: a serial fold over the item's own ids (its
-  run of the sorted chunk) reads each id as a scalar from SMEM and updates
-  one accumulator row at a time.
+* MAX/MIN/OR/XOR have no MXU form: a serial fold over the item's own ids
+  (its run of the sorted chunk) reads each id as a scalar from SMEM and
+  updates one accumulator row at a time.
 * per-row ``touched`` masks implement the paper's dirty-merge optimization:
   rows of a visited block that no update wrote are merged as the identity
   (left bit-exact).
@@ -57,7 +57,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-MERGE_KINDS = ("add", "sat_add", "max", "min", "or")
+MERGE_KINDS = ("add", "sat_add", "max", "min", "or", "xor")
 
 # VMEM the kernel's tiles may take: half of the 16 MiB scoped default on
 # v5e, leaving room for Mosaic's own internal scratch.
@@ -93,7 +93,7 @@ def _identity(kind: str, dtype):
         # iinfo covers unsigned dtypes too (identity = dtype's max value).
         return jnp.asarray(jnp.finfo(dtype).max if _is_float(dtype)
                            else jnp.iinfo(dtype).max, dtype)
-    if kind == "or":
+    if kind in ("or", "xor"):
         return jnp.zeros((), dtype)
     raise ValueError(kind)
 
@@ -267,6 +267,8 @@ def _kernel(item_block_ref, item_at_ref, n_items_ref, ids_ref, vals_ref,
                         new = jnp.maximum(cur, v)
                     elif kind == "min":
                         new = jnp.minimum(cur, v)
+                    elif kind == "xor":
+                        new = cur ^ v
                     else:
                         new = cur | v
                     acc_ref[pl.ds(r, 1), :] = new
@@ -291,6 +293,8 @@ def _kernel(item_block_ref, item_at_ref, n_items_ref, ids_ref, vals_ref,
             new = jnp.maximum(mem, u.astype(mem.dtype))
         elif kind == "min":
             new = jnp.minimum(mem, u.astype(mem.dtype))
+        elif kind == "xor":
+            new = mem ^ u.astype(mem.dtype)
         else:  # or
             new = mem | u.astype(mem.dtype)
         out_ref[...] = jnp.where(touched_ref[...] > 0, new, mem)  # dirty skip

@@ -23,11 +23,13 @@ def _combine(kind: str, a, b):
         return jnp.minimum(a, b)
     if kind == "or":
         return a | b
+    if kind == "xor":
+        return a ^ b
     raise ValueError(kind)
 
 
 def _identity_like(kind: str, x):
-    if kind in ("add", "sat_add", "or"):
+    if kind in ("add", "sat_add", "or", "xor"):
         return jnp.zeros_like(x)
     if jnp.issubdtype(x.dtype, jnp.floating):
         f = jnp.finfo(x.dtype)
@@ -46,6 +48,8 @@ def _apply(kind: str, mem, u, sat_min=0.0, sat_max=0.0):
         return jnp.maximum(mem, u.astype(mem.dtype))
     if kind == "min":
         return jnp.minimum(mem, u.astype(mem.dtype))
+    if kind == "xor":
+        return mem ^ u.astype(mem.dtype)
     return mem | u.astype(mem.dtype)
 
 
@@ -70,10 +74,10 @@ def ref_cscatter(table, ids, vals, kind="add", sat_min=0.0, sat_max=0.0):
                       if jnp.issubdtype(acc_dtype, jnp.floating)
                       else jnp.iinfo(acc_dtype).max)
         u = u.at[safe].min(v)
-    else:  # or — no at[].or_; serial fold over the stream
+    else:  # or, xor — no at[].or_ or at[].xor; serial fold over the stream
         def body(u, iv):
             i, val, ok = iv
-            row = u[i] | jnp.where(ok, val, 0)
+            row = _combine(kind, u[i], jnp.where(ok, val, 0))
             return u.at[i].set(row), None
         u, _ = jax.lax.scan(body, u, (safe, v, valid))
     touched = jnp.zeros((table.shape[0],), bool).at[safe].max(valid)

@@ -23,6 +23,15 @@ production.
 lives on exactly one shard; reads route by ``key % shards``) and bounds
 pending state with ring/spill buffers; ``--overlap`` additionally
 pipelines the commit's launch/land halves (requires ``--partitioned``).
+
+``--merge`` picks the update: ``add`` counts (every value 1), ``xor``
+serves HPCC RandomAccess (a 64-bit word is ``--cols 2`` int32 lanes, each
+update XORs a random word in), checked against numpy's
+``bitwise_xor.at``. XOR is neither scalable nor idempotent, so it cannot
+``--overlap``:
+
+    PYTHONPATH=src python -m repro.launch.kv_serve --shards 4 \
+        --merge xor --cols 2 --partitioned --dist uniform
 """
 
 from __future__ import annotations
@@ -56,6 +65,8 @@ def _parse_args(argv=None):
                         "(requires --partitioned)")
     p.add_argument("--spill-blocks", type=int, default=64,
                    help="blocked engine, partitioned: spill buffer slots")
+    p.add_argument("--merge", default="add", choices=["add", "xor"],
+                   help="the update: add counts, xor HPCC's word ^= value")
     p.add_argument("--consistency", default="eventual",
                    choices=["eventual", "read_your_writes"])
     p.add_argument("--engine", default="kernel",
@@ -108,6 +119,7 @@ def main(argv=None) -> None:
     from repro.core.defer_schedule import (AdaptiveDeferSchedule,
                                            DeferSchedule,
                                            solve_defer_schedule)
+    from repro.core.merge_functions import default_registry
     from repro.launch import hlo_cost
     from repro.serve import KVConfig, ShardedKV, serving_plan
 
@@ -117,6 +129,7 @@ def main(argv=None) -> None:
     spmd = mesh_spmd(build_mesh(S, axis), axis)
 
     cfg = KVConfig(n_keys=R, cols=D, dtype=jnp.int32,
+                   merge=default_registry()[args.merge],
                    consistency=args.consistency, engine=args.engine,
                    ways=args.ways, partitioned=args.partitioned,
                    spill_blocks=args.spill_blocks)
@@ -219,7 +232,11 @@ def main(argv=None) -> None:
     keys = key_stream(args.ticks * S * B, R, args.dist,
                       n_users=args.users, seed=args.seed
                       ).reshape(args.ticks, S, B)
-    vals = np.ones((args.ticks, S, B, D), np.int32)
+    if args.merge == "xor":
+        vals = np.random.default_rng(args.seed + 1).integers(
+            -2**31, 2**31, (args.ticks, S, B, D)).astype(np.int32)
+    else:
+        vals = np.ones((args.ticks, S, B, D), np.int32)
 
     kv.tick(keys[0], vals[0])  # compile
     jax.block_until_ready(kv.settled)
@@ -232,13 +249,20 @@ def main(argv=None) -> None:
 
     kv.flush()
     tbl = kv.table()
-    total = int(tbl[:, 0].astype(np.int64).sum())
     print(f"{args.dist} stream: {args.ticks} ticks x {S} shards x {B} "
-          f"updates, defer={args.defer}, engine={args.engine}")
+          f"updates, merge={args.merge}, defer={args.defer}, "
+          f"engine={args.engine}")
     print(f"ingest: {wall:.3f}s  ({ups:,.0f} updates/s, "
           f"{ups / 1e9:.6f} GUPS)")
-    print(f"settled mass col0: {total} "
-          f"(= {S * B * args.ticks} updates ingested)")
+    if args.merge == "xor":
+        ref = np.zeros((R, D), np.int32)
+        np.bitwise_xor.at(ref, keys.reshape(-1), vals.reshape(-1, D))
+        print(f"table equals numpy bitwise_xor.at: "
+              f"{bool(np.array_equal(tbl, ref))}")
+    else:
+        total = int(tbl[:, 0].astype(np.int64).sum())
+        print(f"settled mass col0: {total} "
+              f"(= {S * B * args.ticks} updates ingested)")
     for k, v in kv.counters().items():
         if k != "schedule":
             print(f"  {k}: {v}")

@@ -44,7 +44,15 @@ bounded ring (``max_period * batch`` slots — overflow is impossible by
 construction, a full commit fires within ``max_period`` ticks and resets
 the cursor), and the blocked engine's resident cache spills evicted blocks
 into a bounded :class:`~repro.core.blocked.SpillBuffer` instead of a dense
-table (spill-through-eviction).  Commits still settle the FULL cascade on
+table (spill-through-eviction).  A kernel-engine commit that does not
+overlap routes the ring home: one all-gather of every shard's ring, after
+which each shard folds the updates it homes into its settled rows, in
+place, through the scatter kernel — no table-sized buffer, so the table
+can fill the device.  Its settled rows are lane-dense: a narrow row of
+``cols`` lanes shares a 128-lane line with ``128 // cols`` others (local
+row ``l`` in line ``l // P``), the layout a ``(rows, cols)`` array would
+take row-major.  The
+blocked engine and the overlapped commit still settle the FULL cascade on
 a transient dense delta — same collectives, same manifest — and each shard
 keeps only its home rows of the aggregate.  ``DeferSchedule(overlap=True)``
 additionally splits the commit into launch/land halves
@@ -77,11 +85,22 @@ Array = jax.Array
 
 _CONSISTENCY = ("eventual", "read_your_writes")
 _ENGINES = ("kernel", "blocked")
-# merge kinds the scatter phase (Pallas kernel / jnp oracle) understands,
-# keyed by the MergeFn's fused-collective op.
-_KERNEL_KINDS = {"add": "add", "max": "max", "min": "min", "or": "or"}
+# merge kinds the scatter phase (Pallas kernel / jnp oracle) understands:
+# a MergeFn's fixed op (``xla_reduce``) names its kind.
+_KERNEL_KINDS = ("add", "max", "min", "or", "xor")
 
 DEFAULT_COMMIT_EVERY = 8
+# lanes of a vector register, and of a lane-dense settled line
+LANES = 128
+
+
+def _valid(keys: Array, n_keys: int) -> Array:
+    """Keys in ``[0, n_keys)``; a key space as wide as the key dtype needs
+    only the sign test."""
+    ok = keys >= 0
+    if n_keys <= jnp.iinfo(keys.dtype).max:
+        ok = ok & (keys < n_keys)
+    return ok
 
 
 def _named(fn: Callable, name: str) -> Callable:
@@ -329,9 +348,20 @@ class ShardedKV:
             if self._overlap:
                 merge.check_overlap("ShardedKV(partitioned, overlap)")
 
+        # a kernel-engine commit that does not overlap routes the ring home
+        # (module doc: partitioned); its settled rows are lane-dense,
+        # ``_pack`` narrow rows to a line
+        self._routed = (config.partitioned and config.engine == "kernel"
+                        and not self._overlap)
         # -- device state (leading shard axis) ------------------------------
         S, R, D = n_shards, config.n_keys, config.cols
-        if config.partitioned:
+        self._pack = 1
+        if self._routed:
+            if D < LANES and LANES % D == 0 and (R // S) % (LANES // D) == 0:
+                self._pack = LANES // D
+            self.settled = self._init_settled()
+            self.pendings = ()
+        elif config.partitioned:
             self.settled = jnp.broadcast_to(
                 merge.identity((R // S, D), config.dtype), (S, R // S, D))
             self.pendings = ()
@@ -398,6 +428,17 @@ class ShardedKV:
     # per-shard program builders (closures created once, see class doc)
     # ------------------------------------------------------------------
 
+    def _init_settled(self) -> Array:
+        """The routed store's settled lines, made on each shard's own
+        device (a table that fills the devices never passes through one)."""
+        cfg, S, P = self.config, self.n_shards, self._pack
+        shape = (cfg.n_keys // S // P, cfg.cols * P)
+
+        def kv_init(_):
+            return cfg.merge.identity(shape, cfg.dtype)
+
+        return self.spmd(kv_init, jnp.zeros((S,), jnp.int32))
+
     @_scoped("identity")
     def _identity_table(self) -> Array:
         cfg = self.config
@@ -411,12 +452,12 @@ class ShardedKV:
         kernel kinds ``apply == combine``, so ``scatter(pending, ...)``
         equals ``combine(pending, scatter(identity, ...))``)."""
         cfg = self.config
-        kind = _KERNEL_KINDS[cfg.merge.xla_reduce]
+        kind = cfg.merge.xla_reduce
         on_tpu = jax.default_backend() == "tpu"
         if kind == "add" and not on_tpu:
             # one-pass fused scatter-add: no identity table, no touched
             # mask — the oracle's passes cost full table sweeps
-            ok = (keys >= 0) & (keys < cfg.n_keys)
+            ok = (keys >= 0) & (keys < table.shape[0])
             safe = jnp.where(ok, keys, 0).astype(jnp.int32)
             return table.at[safe].add(
                 jnp.where(ok[:, None], vals, jnp.zeros_like(vals)))
@@ -556,6 +597,36 @@ class ShardedKV:
                 self.config.merge.identity(rv.shape, rv.dtype),
                 jnp.zeros_like(cur))
 
+    @_scoped("route")
+    def _route(self, ring):
+        """Every shard's ring, gathered on each: keys ``[S*C]``, values
+        ``[S*C, cols]``."""
+        rk, rv, _ = ring
+        return (jax.lax.all_gather(rk, self.axis_name, tiled=True),
+                jax.lax.all_gather(rv, self.axis_name, tiled=True))
+
+    def _settle_home(self, settled: Array, keys: Array, vals: Array) -> Array:
+        """Fold the updates this shard homes into its settled lines, in
+        place: global key ``k`` is local row ``l = k // S``, which sits in
+        line ``l // P`` at lanes ``(l % P) * cols`` on; the line's other
+        lanes take the merge identity."""
+        cfg, S, P, D = self.config, self.n_shards, self._pack, \
+            self.config.cols
+        me = jax.lax.axis_index(self.axis_name)
+        ok = _valid(keys, cfg.n_keys) & (keys % S == me)
+        local = keys // S
+        if P > 1:
+            with jax.named_scope("pack"):
+                lane = jnp.arange(P * D) // D
+                vals = jnp.where(lane[None, :] == (local % P)[:, None],
+                                 jnp.tile(vals, (1, P)),
+                                 cfg.merge.identity((), vals.dtype))
+        return self._scatter_into(settled, jnp.where(ok, local // P, -1),
+                                  vals)
+
+    def _commit_home(self, settled: Array, ring) -> Array:
+        return self._settle_home(settled, *self._route(ring))
+
     def _part_delta(self, ring) -> Array:
         """The ring's buffered updates as a transient dense global delta
         (unwritten slots hold key ``-1`` — scatter's ignore convention)."""
@@ -584,17 +655,22 @@ class ShardedKV:
     def _make_part_tick(self, full: bool, land: bool):
         overlap = self._overlap
 
-        if self.config.engine == "kernel" and not land:
+        if self._routed:
+            def tick(settled, ring, keys, vals):
+                ring = self._ring_append(ring, keys, vals)
+                if not full:
+                    return settled, ring
+                return (self._commit_home(settled, ring),
+                        self._ring_reset(ring))
+        elif self.config.engine == "kernel" and not land:
+            # overlapped: the commit tick launches the dense delta
             def tick(settled, ring, keys, vals):
                 ring = self._ring_append(ring, keys, vals)
                 if not full:
                     return settled, ring
                 delta = self._part_delta(ring)
                 ring = self._ring_reset(ring)
-                if overlap:
-                    return settled, ring, self._launch(delta)
-                agg = self._settle(delta)
-                return self._apply(settled, self._home_rows(agg)), ring
+                return settled, ring, self._launch(delta)
         elif self.config.engine == "kernel":
             def tick(settled, ring, inflight, keys, vals):
                 ring = self._ring_append(ring, keys, vals)
@@ -644,7 +720,11 @@ class ShardedKV:
         def land_home(settled, inflight):
             return self._apply(settled, self._home_rows(self._land(inflight)))
 
-        if self.config.engine == "kernel" and not land:
+        if self._routed:
+            def flush_fn(settled, ring):
+                return (self._commit_home(settled, ring),
+                        self._ring_reset(ring))
+        elif self.config.engine == "kernel" and not land:
             def flush_fn(settled, ring):
                 settled = settle_home(settled, self._part_delta(ring))
                 return settled, self._ring_reset(ring)
@@ -668,17 +748,23 @@ class ShardedKV:
     def _make_part_read(self, kind: str):
         cfg = self.config
         merge = cfg.merge
-        S, R, D = self.n_shards, cfg.n_keys, cfg.cols
+        S, R, D, P = self.n_shards, cfg.n_keys, cfg.cols, self._pack
 
         def base_gather(settled, keys):
             # routed reads: only keys homed here answer; off-home or
             # invalid keys return the merge identity (route with
             # BatchedFrontend, which shards traffic by key % n_shards)
             me = jax.lax.axis_index(self.axis_name)
-            ok = (keys >= 0) & (keys < R) & (keys % S == me)
+            ok = _valid(keys, R) & (keys % S == me)
             local = jnp.where(ok, keys // S, 0)
+            if P == 1:
+                rows = settled[local]
+            else:
+                rows = jnp.take_along_axis(
+                    settled[local // P].reshape(-1, P, D),
+                    (local % P)[:, None, None], axis=1)[:, 0]
             ident = merge.identity((D,), cfg.dtype)
-            return jnp.where(ok[:, None], settled[local], ident), ok
+            return jnp.where(ok[:, None], rows, ident), ok
 
         if kind == "plain":
             def read(settled, keys):
@@ -1001,11 +1087,18 @@ class ShardedKV:
         (``out[s::S] = shard s``)."""
         if not self.partitioned:
             return np.asarray(self.settled[0])
-        parts = np.asarray(self.settled)            # (S, R // S, D)
+        parts = self.home_tables()
         out = np.empty((self.config.n_keys, self.config.cols), parts.dtype)
         for s in range(self.n_shards):
             out[s::self.n_shards] = parts[s]
         return out
+
+    def home_tables(self) -> np.ndarray:
+        """A partitioned store's settled rows, ``(S, n_keys // S, cols)``:
+        shard ``s``'s local row ``l`` holds global key ``l * S + s``."""
+        cfg = self.config
+        return np.asarray(self.settled).reshape(
+            self.n_shards, cfg.n_keys // self.n_shards, cfg.cols)
 
     # ------------------------------------------------------------------
     # durability: write-ahead journal + flush-consistent snapshots
@@ -1057,7 +1150,8 @@ class ShardedKV:
                              f"({cfg.n_keys}, {cfg.cols})")
         if self.partitioned:
             parts = np.stack([table[s::S] for s in range(S)])
-            self.settled = jnp.asarray(parts, cfg.dtype)
+            self.settled = jnp.asarray(parts.reshape(self.settled.shape),
+                                       cfg.dtype)
         else:
             self.settled = jnp.broadcast_to(
                 jnp.asarray(table, cfg.dtype), (S,) + table.shape)
@@ -1226,8 +1320,8 @@ class ShardedKV:
         keys = jax.ShapeDtypeStruct((batch,), jnp.int32)
         vals = jax.ShapeDtypeStruct((batch, cfg.cols), self.settled.dtype)
         if self.partitioned:
-            settled = jax.ShapeDtypeStruct(
-                (cfg.n_keys // self.n_shards, cfg.cols), self.settled.dtype)
+            settled = jax.ShapeDtypeStruct(self.settled.shape[1:],
+                                           self.settled.dtype)
             inflight = ((jax.ShapeDtypeStruct((cfg.n_keys, cfg.cols),
                                               self.settled.dtype),)
                         if land else ())
@@ -1281,6 +1375,8 @@ class ShardedKV:
                                               merge_fn=self.config.merge)
         if due is None:
             due = self.n_deferred
+        if self._routed:
+            return [self._route_manifest()] if due == self.n_deferred else []
         if self.partitioned and self._overlap:
             out = []
             if land:
@@ -1294,3 +1390,12 @@ class ShardedKV:
             return out
         return ccache.program_manifest(self.plan, self.n_shards, due,
                                        merge_fn=self.config.merge)
+
+    def _route_manifest(self) -> ccache.StageManifest:
+        """The routed commit's one exchange: an all-gather over the whole
+        axis, on the plan's top level."""
+        top = max(i for i, lv in enumerate(self.plan.levels) if lv.size > 1)
+        return ccache.StageManifest(
+            index=top, name="route", defer=True, stride=1,
+            fanout=self.n_shards, kind="gather", fused_ops=0,
+            exchange_rounds=0, intra_rounds=0)

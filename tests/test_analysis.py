@@ -237,3 +237,32 @@ def test_report_warnings_do_not_fail():
 def test_unknown_code_rejected():
     with pytest.raises(ValueError):
         Diagnostic(code="CC999", site="t", message="x")
+
+
+# ---------------------------------------------------------------------------
+# XOR (HPCC RandomAccess): certified on bit patterns, software exchange
+# ---------------------------------------------------------------------------
+
+
+def test_verifier_certifies_xor_and_refutes_a_mislabeled_one():
+    import dataclasses
+    from repro.core.merge_functions import BITWISE_XOR
+    assert certify_merge_fn(BITWISE_XOR) == []
+    idem = dataclasses.replace(BITWISE_XOR, name="xor_idem", idempotent=True)
+    assert any(d.code == "CC001" for d in certify_merge_fn(idem))
+    scal = dataclasses.replace(BITWISE_XOR, name="xor_scal", scalable=True)
+    assert any(d.code == "CC002" for d in certify_merge_fn(scal))
+
+
+def test_xor_manifest_is_the_software_butterfly():
+    """XOR has no fused collective: the four-chip serving plan's innermost
+    stage is a one-round butterfly, where ADD fuses into an all-reduce."""
+    from repro.core.merge_functions import BITWISE_XOR
+    plan = serving_plan(4, "all")
+    xor = ccache.collective_manifest(plan, 4, merge_fn=BITWISE_XOR)
+    add = ccache.collective_manifest(plan, 4, merge_fn=ADD)
+    assert [m.name for m in xor] == [m.name for m in add]
+    assert xor[0].kind == "butterfly" and xor[0].fused_ops == 0
+    assert xor[0].permute_rounds == 1
+    assert add[0].kind == "fused" and add[0].permute_rounds == 0
+    assert xor[1:] == add[1:]

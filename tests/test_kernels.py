@@ -296,3 +296,56 @@ def test_embedding_grad_scatter_equals_autodiff():
                                      block_rows=16, chunk=64)
     np.testing.assert_allclose(np.asarray(got), np.asarray(gold),
                                rtol=1e-4, atol=1e-4)
+
+
+# ------------------------------------------------------------- cscatter xor
+
+
+def _numpy_xor(table, ids, vals):
+    want = np.asarray(table).copy()
+    i = np.asarray(ids)
+    ok = (i >= 0) & (i < want.shape[0])
+    np.bitwise_xor.at(want, i[ok], np.asarray(vals)[ok])
+    return want
+
+
+@pytest.mark.parametrize("case", WORK_LIST_CASES)
+def test_cscatter_xor_matches_numpy(case):
+    """The XOR kind (the serial fold over each block's own ids) and both
+    jnp oracles equal ``np.bitwise_xor.at`` bit for bit; blocks no valid
+    id touches are left as they were."""
+    table, ids, vals, br, ch = _work_list_case(
+        case, "xor", np.random.default_rng(40 + WORK_LIST_CASES.index(case)))
+    out = cscatter(table, ids, vals, kind="xor", block_rows=br, chunk=ch)
+    want = _numpy_xor(table, ids, vals)
+    assert np.array_equal(np.asarray(out), want)
+    assert np.array_equal(np.asarray(ref.ref_cscatter(table, ids, vals,
+                                                      "xor")), want)
+    assert np.array_equal(np.asarray(ref.ref_cscatter_serial(
+        table, ids, vals, "xor")), want)
+    r = table.shape[0]
+    ok = np.asarray((ids >= 0) & (ids < r))
+    hit = np.zeros(-(-r // br), bool)
+    hit[np.asarray(ids)[ok] // br] = True
+    cold = ~np.repeat(hit, br)[:r]
+    assert jnp.array_equal(out[cold], table[cold])
+
+
+def test_cscatter_xor_pairs_cancel_at_the_default_tile():
+    """Every update sent twice cancels: the table comes back bit for bit,
+    at the tile ``cscatter`` chooses for a 64-bit-word table."""
+    rng = np.random.default_rng(3)
+    r, n = 1 << 14, 600
+    table = jnp.asarray(rng.integers(-2**31, 2**31, (r, 2)).astype(np.int32))
+    ids = rng.integers(0, r, n).astype(np.int32)
+    vals = rng.integers(-2**31, 2**31, (n, 2)).astype(np.int32)
+    ids2, vals2 = np.concatenate([ids, ids[::-1]]), np.concatenate(
+        [vals, vals[::-1]])
+    out = cscatter(table, jnp.asarray(ids2), jnp.asarray(vals2), kind="xor")
+    assert jnp.array_equal(out, table)
+    out = cscatter(table, jnp.asarray(ids), jnp.asarray(vals), kind="xor")
+    assert np.array_equal(np.asarray(out), _numpy_xor(table, ids, vals))
+    br, ch = choose_tile("xor", r, n, 2)
+    assert tile_bytes("xor", br, ch, 2) <= VMEM_BUDGET
+    assert int(visits(jnp.asarray(ids), r, 2, "xor")) == int(
+        visits(jnp.asarray(ids), r, 2, "or"))

@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.core import ccache
 from repro.core.defer_schedule import DeferSchedule
 from repro.core.merge_functions import MAX
 from repro.serve import BatchedFrontend, KVConfig, ShardedKV, serving_plan
@@ -407,6 +408,37 @@ def test_partitioned_noncommit_tick_traces_zero_collectives():
     assert kv.supported_dues == (0, kv.n_deferred)
 
 
+@pytest.mark.parametrize("cols,packed", [(2, True), (4, True), (3, False)])
+def test_routed_commit_matches_reference_in_either_layout(cols, packed):
+    """A partitioned commit that does not overlap routes every ring home;
+    narrow rows pack lane-dense, ``128 // cols`` to a line, where a shard's
+    rows fill whole lines. After each commit and the flush the table and
+    the routed gets equal the reference, bit for bit."""
+    S, R, B, K, T = 4, 1024, 8, 4, 10
+    kv = ShardedKV(KVConfig(n_keys=R, cols=cols, partitioned=True), S,
+                   _spmd, commit_every=K)
+    P = 128 // cols if packed else 1
+    assert kv.settled.shape == (S, R // S // P, cols * P)
+    rng = np.random.default_rng(cols)
+    keys = rng.integers(0, R, (T, S, B)).astype(np.int32)
+    keys[:, :, -1] = -1
+    vals = rng.integers(-2**31, 2**31, (T, S, B, cols)).astype(np.int32)
+    ref = np.zeros((R, cols), np.int64)
+    for t in range(T):
+        kv.tick(keys[t], vals[t])
+        ok = keys[t].reshape(-1) >= 0
+        np.add.at(ref, keys[t].reshape(-1)[ok],
+                  vals[t].reshape(-1, cols)[ok])
+        if (t + 1) % K == 0:
+            assert np.array_equal(kv.table(), ref.astype(np.int32)), t
+    kv.flush()
+    want = ref.astype(np.int32)
+    assert np.array_equal(kv.table(), want)
+    rkeys = np.arange(R, dtype=np.int32).reshape(R // S, S).T
+    got = np.asarray(kv.read(rkeys))
+    assert all(np.array_equal(got[s], want[rkeys[s]]) for s in range(S))
+
+
 def test_partitioned_resident_footprint_bounded():
     """The point of the tentpole: per-device resident bytes stop scaling
     with n_keys * (1 + n_deferred) and drop >= 4x vs the replicated
@@ -436,11 +468,16 @@ def test_partitioned_spill_overflow_raises_loudly():
 
 
 def test_partitioned_scheduled_manifests():
-    """Non-commit ticks are licensed to emit nothing; the overlapped
-    halves partition the full-commit manifest exactly."""
+    """Non-commit ticks are licensed to emit nothing; a commit that does
+    not overlap routes the ring home in one all-gather; the overlapped
+    halves partition the full cascade's manifest exactly."""
     kv = ShardedKV(_part_cfg("kernel"), 8, _spmd, commit_every=4)
     assert kv.scheduled_manifest(0) == []
-    full = kv.scheduled_manifest()
+    routed = kv.scheduled_manifest()
+    assert [(m.name, m.kind, m.fanout) for m in routed] == [
+        ("route", "gather", 8)]
+    full = ccache.program_manifest(kv.plan, 8, kv.n_deferred,
+                                   merge_fn=kv.config.merge)
     assert [m.name for m in full] == list(kv._deferred_names)
 
     ov = ShardedKV(_part_cfg("kernel"), 8, _spmd,
@@ -593,3 +630,160 @@ def test_kv_store_on_forced_8_device_mesh():
                 if ln.startswith("RESULT "))
     out = json.loads(line[len("RESULT "):])
     assert out == {"sync_matches_oracle": True, "priv_matches_sync": True}
+
+
+# ---------------------------------------------------------------------------
+# HPCC RandomAccess: XOR of 64-bit words (two int32 lanes) on 4 host devices
+# ---------------------------------------------------------------------------
+
+XOR_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json, re
+    import numpy as np
+    import jax
+    from repro.analysis import placement
+    from repro.apps.sharded import build_mesh, mesh_spmd
+    from repro.core.ccache import deferred_stages_of
+    from repro.core.defer_schedule import DeferSchedule
+    from repro.core.merge_functions import BITWISE_XOR
+    from repro.launch import hlo_cost
+    from repro.serve import KVConfig, ShardedKV, serving_plan
+
+    S, R, D, B, K, T = 4, 256, 2, 16, 8, 27
+    spmd = mesh_spmd(build_mesh(S))
+    plan = serving_plan(S, "all")
+    levels = tuple(s.name for s in deferred_stages_of(plan, S,
+                                                      merge_fn=BITWISE_XOR))
+    part = ShardedKV(KVConfig(n_keys=R, cols=D, merge=BITWISE_XOR,
+                              partitioned=True), S, spmd, plan=plan,
+                     schedule=DeferSchedule.fixed(K, levels))
+    sync = ShardedKV(KVConfig(n_keys=R, cols=D, merge=BITWISE_XOR), S,
+                     spmd, plan=serving_plan(S, "none"))
+    rng = np.random.default_rng(0)
+    keys = rng.integers(0, R, (T, S, B)).astype(np.int32)
+    keys[:, :, -1] = -1
+    vals = rng.integers(-2**31, 2**31, (T, S, B, D)).astype(np.int32)
+
+    def ref(n):
+        want = np.zeros((R, D), np.int32)
+        k = keys[:n].reshape(-1)
+        ok = k >= 0
+        np.bitwise_xor.at(want, k[ok], vals[:n].reshape(-1, D)[ok])
+        return want
+
+    out = {"commits": [], "sync": []}
+    for t in range(T):
+        part.tick(keys[t], vals[t])
+        sync.tick(keys[t], vals[t])
+        out["sync"].append(bool(np.array_equal(sync.table(), ref(t + 1))))
+        if (t + 1) % K == 0:
+            out["commits"].append(bool(np.array_equal(part.table(),
+                                                      ref(t + 1))))
+    out["unflushed_differs"] = not np.array_equal(part.table(), ref(T))
+    part.flush()
+    out["flushed"] = bool(np.array_equal(part.table(), ref(T)))
+    rkeys = np.arange(R, dtype=np.int32).reshape(R // S, S).T
+    got = np.asarray(part.read(rkeys))
+    out["gets_home"] = all(np.array_equal(got[s], ref(T)[rkeys[s]])
+                           for s in range(S))
+    out["gets_off_home"] = bool(
+        (np.asarray(part.read(np.roll(rkeys, 1, axis=0))) == 0).all())
+
+    sizes = tuple(lv.size for lv in plan.levels)
+    names = tuple(lv.name for lv in plan.levels)
+    def walk(store, fn, specs):
+        args = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            (S,) + s.shape, s.dtype), specs)
+        low = store.spmd.lower(fn, *args)
+        hlo = low.compiler_ir("hlo").get_hlo_module().to_string()
+        text = low.compile().as_text()
+        return hlo, hlo_cost.analyze_hlo(text, level_sizes=sizes,
+                                         level_names=names)
+    out["settled_shape"] = list(part.settled.shape)
+    hlo, w = walk(part, part.raw_tick_fn(part.n_deferred),
+                  part.tick_arg_specs(B))
+    out["commit_module"] = part.raw_tick_fn(part.n_deferred).__name__
+    out["commit_route_ops"] = sorted(set(re.findall(
+        r"op_name=\\"(?:[^\\"]*/)?route/[^\\"]*?(all_gather)", hlo)))
+    out["commit_cc021"] = [d.format() for d in placement.check_commit_walk(
+        w, part.scheduled_manifest(), "xor:commit")]
+    _, w = walk(part, part.raw_tick_fn(0), part.tick_arg_specs(B))
+    out["ring_cc020"] = [d.format() for d in placement.check_noncommit_walk(
+        w, "xor:ring")]
+    hlo, w = walk(sync, sync.raw_tick_fn(), sync.tick_arg_specs(B))
+    out["sync_exchange_ops"] = sorted(set(re.findall(
+        r"op_name=\\"[^\\"]*/exchange/[^\\"]*?(ppermute|xor)", hlo)))
+    out["sync_cc021"] = [d.format() for d in placement.check_commit_walk(
+        w, sync.scheduled_manifest(), "xor:sync")]
+    print("RESULT " + json.dumps(out))
+""")
+
+
+@pytest.fixture(scope="module")
+def xor_report():
+    r = subprocess.run([sys.executable, "-c", XOR_SCRIPT], env=ENV,
+                       capture_output=True, text=True, timeout=900)
+    assert r.returncode == 0, r.stderr[-3000:]
+    line = next(ln for ln in r.stdout.splitlines()
+                if ln.startswith("RESULT "))
+    return json.loads(line[len("RESULT "):])
+
+
+def test_xor_partitioned_deferred_store_equals_numpy(xor_report):
+    """Partitioned, a commit every 8 ticks, no overlap: after each of three
+    commits and after the flush the table is ``np.bitwise_xor.at`` of every
+    update, bit for bit; between commits it is not yet."""
+    assert xor_report["commits"] == [True, True, True]
+    assert xor_report["unflushed_differs"]
+    assert xor_report["flushed"]
+
+
+def test_xor_synchronized_store_equals_numpy_every_tick(xor_report):
+    assert len(xor_report["sync"]) == 27 and all(xor_report["sync"])
+
+
+def test_xor_gets_are_routed_home(xor_report):
+    assert xor_report["gets_home"] and xor_report["gets_off_home"]
+
+
+def test_xor_exchanges_run_to_their_manifests(xor_report):
+    """The partitioned commit routes the rings home in an all-gather under
+    the scope ``route``; the synchronized tick's software butterfly, its
+    ppermutes and XOR combines, runs under ``exchange``. Each program's
+    collectives match its scheduled manifest (CC021); the ring tick moves
+    nothing (CC020)."""
+    assert xor_report["commit_module"] == "kv_tick_commit"
+    assert xor_report["commit_route_ops"] == ["all_gather"]
+    assert xor_report["sync_exchange_ops"] == ["ppermute", "xor"]
+    assert xor_report["commit_cc021"] == []
+    assert xor_report["sync_cc021"] == []
+    assert xor_report["ring_cc020"] == []
+
+
+def test_xor_words_are_packed_lane_dense(xor_report):
+    """Two-lane words, 64 to a 128-lane line: 256 keys over 4 shards are one
+    line a shard."""
+    assert xor_report["settled_shape"] == [4, 1, 128]
+
+
+def test_xor_overlapped_commit_still_raises():
+    """XOR is neither scalable nor idempotent: a one-step-stale landing is
+    refused when the store is built."""
+    from repro.core.merge_functions import BITWISE_XOR
+    cfg = KVConfig(n_keys=32, cols=2, merge=BITWISE_XOR, partitioned=True)
+    probe = ShardedKV(cfg, 4, _spmd, commit_every=8)
+    with pytest.raises(ValueError, match="stale"):
+        ShardedKV(cfg, 4, _spmd, schedule=DeferSchedule.fixed(
+            8, probe._deferred_names, overlap=True))
+
+
+def test_kv_serve_cli_serves_xor():
+    r = subprocess.run(
+        [sys.executable, "-m", "repro.launch.kv_serve", "--shards", "4",
+         "--merge", "xor", "--cols", "2", "--partitioned", "--dist",
+         "uniform", "--keys", "4096", "--ticks", "20", "--batch", "64"],
+        env=ENV, capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "merge=xor" in r.stdout
+    assert "table equals numpy bitwise_xor.at: True" in r.stdout

@@ -197,9 +197,10 @@ def test_no_program_is_left_unnamed(report):
 SCOPES = [("scatter", "kv_tick_launch"), ("identity", "kv_tick_launch"),
           ("ring_append", "kv_tick_ring"), ("ring_reset", "kv_tick_launch"),
           ("launch", "kv_tick_launch"), ("land", "kv_tick_land"),
-          ("settle", "kv_tick_commit"), ("settle", "kv_flush"),
+          ("settle", "blocked:kv_tick_commit"), ("settle", "kv_flush"),
           ("settle", "kv_tick_commit_2"), ("home_rows", "kv_tick_land"),
-          ("apply", "kv_tick_commit"), ("apply", "kv_tick_sync"),
+          ("apply", "blocked:kv_tick_commit"), ("apply", "kv_tick_sync"),
+          ("route", "kv_tick_commit"), ("scatter", "kv_tick_commit"),
           ("read", "kv_read"), ("read", "kv_read_ryw_inflight")]
 
 

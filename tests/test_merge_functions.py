@@ -204,3 +204,48 @@ def test_registry_mfrf():
     small.merge_init(mf.ADD)
     with pytest.raises(ValueError):
         small.merge_init(mf.MAX)
+
+
+# ------------------------------------------------------------ XOR (HPCC)
+
+
+def test_xor_traits_and_registration():
+    """XOR is self-inverse: invertible and deferrable, neither idempotent
+    (a ^ a == 0) nor scalable, so a deferred commit is exact but a
+    one-step-stale overlapped landing is refused."""
+    x = mf.BITWISE_XOR
+    assert x.invertible and x.deferrable
+    assert not x.idempotent and not x.scalable
+    assert not x.stale_tolerant and x.settle_mode() is None
+    x.check_deferrable("t")
+    with pytest.raises(ValueError, match="stale"):
+        x.check_overlap("t")
+    assert mf.default_registry()["xor"] is x and x in mf.standard_merges()
+    rng = np.random.default_rng(0)
+    a, b = (jnp.asarray(rng.integers(-2**31, 2**31, (8, 2)), jnp.int32)
+            for _ in range(2))
+    zero = x.identity(a.shape, a.dtype)
+    assert jnp.array_equal(x.combine(a, a), zero)
+    assert jnp.array_equal(x.combine(a, zero), a)
+    assert jnp.array_equal(x.combine(a, x.delta(a, b)), b)
+
+
+@pytest.mark.parametrize("k", [1, 3, 8])
+def test_xor_k_deferred_commits_equal_k_eager_merges(k):
+    """K updates combined in a pending and applied once equal K applies,
+    and numpy's XOR of the same words, bit for bit."""
+    x = mf.BITWISE_XOR
+    rng = np.random.default_rng(k)
+    mem = rng.integers(-2**31, 2**31, (16, 2)).astype(np.int32)
+    ups = rng.integers(-2**31, 2**31, (k, 16, 2)).astype(np.int32)
+    eager = jnp.asarray(mem)
+    pending = x.identity(mem.shape, jnp.int32)
+    for u in ups:
+        eager = x.apply(eager, jnp.asarray(u))
+        pending = x.combine(pending, jnp.asarray(u))
+    want = mem.copy()
+    for u in ups:
+        np.bitwise_xor(want, u, out=want)
+    assert np.array_equal(np.asarray(eager), want)
+    assert np.array_equal(np.asarray(x.apply(jnp.asarray(mem), pending)),
+                          want)

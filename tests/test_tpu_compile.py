@@ -120,3 +120,70 @@ def test_store_sync_tick_copies_no_more_tables_for_v5e(
     copies = re.findall(
         rf"= s32\[(?:1,)?{STORE_ROWS},{COLS}\]\S* copy(?:-start)?\(", hlo)
     assert len(copies) <= 2, copies
+
+
+@pytest.mark.parametrize("batch", [1024, 32768])
+def test_cscatter_xor_compiles_for_v5e(one_chip, no_persistent_cache, batch):
+    """A chip's share of HPCC's table, 2^29 64-bit words packed 64 to a
+    128-lane int32 line, takes the XOR kind in place, with a tick's and a
+    routed commit's updates."""
+    table = jax.ShapeDtypeStruct((STORE_ROWS, 128), jnp.int32,
+                                 sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip)
+    vals = jax.ShapeDtypeStruct((batch, 128), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda t, i, v: cscatter(t, i, v, kind="xor", interpret=False),
+        donate_argnums=0).lower(table, ids, vals).compile()
+    (call,) = [line for line in compiled.as_text().splitlines()
+               if "tpu_custom_call" in line]
+    assert re.match(r"\s*(ROOT )?%cscatter\.\d+ = ", call)
+    assert "output_to_operand_aliasing={{}: (5, {})}" in call
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 25
+
+
+def test_xor_store_commit_compiles_for_v5e(topo, no_persistent_cache,
+                                           monkeypatch):
+    """The partitioned XOR store's commit tick compiles for four described
+    v5e chips with the kernel, its collectives are the scheduled route
+    (CC021): an all-gather, no all-reduce. It holds no table-sized
+    temporary: the settled lines are updated in place."""
+    from repro.analysis import placement
+    from repro.apps.sharded import mesh_spmd
+    from repro.core.ccache import deferred_stages_of
+    from repro.core.defer_schedule import DeferSchedule
+    from repro.core.merge_functions import BITWISE_XOR
+    from repro.launch import hlo_cost
+    from repro.serve import KVConfig, ShardedKV, serving_plan
+
+    S = 4
+    mesh = Mesh(np.asarray(topo.devices[:S]), ("shards",))
+    plan = serving_plan(S, "all")
+    levels = tuple(s.name for s in deferred_stages_of(plan, S,
+                                                      merge_fn=BITWISE_XOR))
+    shard = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec(
+        "shards"))
+    spmd = mesh_spmd(mesh)
+    spmd_abstract = lambda fn, *args: jax.eval_shape(  # noqa: E731
+        lambda *a: spmd(fn, *a), *args)
+    store = ShardedKV(
+        KVConfig(n_keys=1 << 28, cols=2, dtype=jnp.int32, merge=BITWISE_XOR,
+                 engine="kernel", partitioned=True),
+        S, spmd_abstract, plan=plan, schedule=DeferSchedule.fixed(8, levels))
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct((S,) + s.shape, s.dtype,
+                                       sharding=shard),
+        store.tick_arg_specs(BATCH))
+    assert args[0].shape == (S, 1 << 20, 128)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = spmd.lower(store.raw_tick_fn(store.n_deferred), *args,
+                          donate=store.donate_argnums).compile()
+    hlo = compiled.as_text()
+    assert "cscatter" in hlo and "all-gather" in hlo
+    assert "all-reduce" not in hlo
+    walk = hlo_cost.analyze_hlo(
+        hlo, level_sizes=tuple(lv.size for lv in plan.levels),
+        level_names=tuple(lv.name for lv in plan.levels))
+    assert placement.check_commit_walk(walk, store.scheduled_manifest(),
+                                       "xor:commit") == []
+    table_bytes = (1 << 20) * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < table_bytes // 8
